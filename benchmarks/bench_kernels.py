@@ -79,9 +79,9 @@ def main(quick=False) -> List[str]:
     rows.append("kernel/mamba_scan/vmem,0,"
                 "state 512x16 f32 = 32KiB resident; one pass over x/dt/B/C")
     rows.append("kernel/dot_seen/vmem,0,"
-                "clock (starts+ends interval arrays) resident ~256KiB "
-                "@ A=128,R=256; one-hot MXU row gather + broadcast interval "
-                "test, dots streamed in 1024-blocks")
+                "clock streamed in [A x 512]-run tiles (~6.6MiB per step "
+                "@ A=8 whatever R); one-hot MXU row gather + broadcast "
+                "interval test, dots in 1024-blocks")
     return rows
 
 

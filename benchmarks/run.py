@@ -26,6 +26,9 @@ def main(argv=None) -> None:
                     help="write a JSON metrics snapshot + rows to PATH")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from . import (bench_antientropy, bench_checkpoint, bench_clock,
                    bench_joins, bench_kernels, bench_lint, bench_mixed,
                    bench_placement, bench_queries, bench_reads,

@@ -523,25 +523,51 @@ class BigsetCluster(_ClusterBase):
         happen at an owner of the element's partition, so the context dots
         and the tombstone live in the same partition clock domain.
         """
+        return self._remove_all(
+            set_name, [(element, ctx)], coordinator, session)[0]
+
+    def _remove_all(self, set_name: bytes,
+                    items: Sequence[Tuple[bytes, Optional[Iterable[Dot]]]],
+                    coordinator: int,
+                    session: Optional[ClusterSession]
+                    ) -> List[Optional[RemoveDelta]]:
+        """Observed-removes of ``(element, ctx or None)`` items.
+
+        Each item is routed and probed on its own, but the contexts bound
+        for one coordinator and partition are absorbed in one clock write
+        and shipped as one delta: writing the tombstone costs O(its runs),
+        so k removes pay that once, not k times.  Returns one delta per
+        item, carrying that item's own context, or None where there was
+        nothing to remove.  The caller keeps the items independent: no
+        probe may follow a remove whose context it could observe.
+        """
         entry = self._coordinator(coordinator, set_name)
-        pref = self.ring.preference_list(set_name, element)
-        pset = self.ring.storage_set(set_name, pref.pid)
-        self._note_set(set_name, pref, pset)
-        actor, targets = self._route_write(entry, set_name, pref)
-        vn = self.vnodes[actor]
-        if ctx is None:
-            _, ctx = vn.is_member(pset, element)
-        ctx = tuple(ctx)
-        if not ctx:
-            return None
-        with self.tracer.span("cluster.remove", set_name=set_name,
-                              actor=actor) as sp:
-            delta = vn.coordinate_remove(pset, ctx)
-            self._replicate_to(actor, targets, self._traced(sp, delta),
-                               delta.size_bytes())
-        if session is not None:
-            session.observe_mutation(delta)
-        return delta
+        out: List[Optional[RemoveDelta]] = []
+        groups: Dict[Tuple, List[Dot]] = {}
+        try:
+            for element, ctx in items:
+                pref = self.ring.preference_list(set_name, element)
+                pset = self.ring.storage_set(set_name, pref.pid)
+                self._note_set(set_name, pref, pset)
+                actor, targets = self._route_write(entry, set_name, pref)
+                if ctx is None:
+                    _, ctx = self.vnodes[actor].is_member(pset, element)
+                ctx = tuple(ctx)
+                out.append(RemoveDelta(pset, ctx) if ctx else None)
+                if ctx:
+                    groups.setdefault(
+                        (pset, actor, tuple(targets)), []).extend(ctx)
+        finally:  # what was routed before a failure is still written
+            for (pset, actor, targets), ctx in groups.items():
+                with self.tracer.span("cluster.remove", set_name=set_name,
+                                      actor=actor) as sp:
+                    delta = self.vnodes[actor].coordinate_remove(pset, ctx)
+                    self._replicate_to(actor, targets,
+                                       self._traced(sp, delta),
+                                       delta.size_bytes())
+                if session is not None:
+                    session.observe_mutation(delta)
+        return out
 
     def mutate(self, set_name: bytes, ops: Sequence[Tuple], coordinator: int = 0,
                session: Optional[ClusterSession] = None) -> List:
@@ -551,21 +577,39 @@ class BigsetCluster(_ClusterBase):
         ``("remove", element[, ctx])`` tuples, applied in order through one
         coordinator so a remove can observe an earlier add in the same
         batch.  Returns the per-op deltas (None for no-op removes).
+
+        A run of removes of distinct elements that carry no context is
+        written as one remove: each probe reads only its own element's
+        keys, which no other remove of the run touches.
         """
         out: List = []
+        probed: Dict[bytes, None] = {}  # insertion-ordered set
+
+        def flush() -> None:
+            if probed:
+                out.extend(self._remove_all(
+                    set_name, [(el, None) for el in probed], coordinator,
+                    session))
+                probed.clear()
+
         for op in ops:
             kind, element = op[0], op[1]
+            ctx = op[2] if kind == "remove" and len(op) > 2 else None
+            if kind == "remove" and ctx is None and element not in probed:
+                probed[element] = None
+                continue
+            flush()
             if kind == "add":
                 value = op[2] if len(op) > 2 else b""
                 ctx = op[3] if len(op) > 3 else ()
                 out.append(self.add(set_name, element, coordinator, ctx=ctx,
                                     value=value, session=session))
             elif kind == "remove":
-                ctx = op[2] if len(op) > 2 else None
                 out.append(self.remove(set_name, element, coordinator,
                                        ctx=ctx, session=session))
             else:
                 raise ValueError(f"unknown mutation op {kind!r}")
+        flush()
         return out
 
     def _handle(self, msg: Message) -> None:

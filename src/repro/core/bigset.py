@@ -34,7 +34,7 @@ from ..storage.keycodec import (KIND_CLOCK, KIND_ELEMENT, KIND_INDEX,
 from ..storage.lsm import TOMBSTONE as STORE_TOMBSTONE
 from ..storage.lsm import LsmIterator, LsmStore
 from .clock import Clock
-from .dots import ActorId, Dot, dot_from_key
+from .dots import ActorId, Dot, as_dot, dot_from_key
 from .orswot import Orswot
 
 
@@ -58,6 +58,29 @@ def _clock_from_bytes(b: Optional[bytes]) -> Clock:
         return Clock.zero()
     o = msgpack.unpackb(b, strict_map_key=False)
     return Clock.from_obj(o)
+
+
+def _absorb_ctx(sc: Clock, ts: Clock,
+                ctx: Iterable[Dot]) -> Tuple[Clock, Clock]:
+    """Fold a causal context into ``(set-clock, tombstone)``.
+
+    A dot the set-clock has seen goes to the tombstone (its key exists or
+    existed: compact it away); an unseen one goes to the set-clock (an add
+    pre-empted before it materialises).  Taken one dot at a time, in
+    order — so a repeated unseen dot lands in both — but merged into each
+    clock in one O(runs) pass, whatever the context's length.
+    """
+    fresh: List[Dot] = []
+    dead: List[Dot] = []
+    minted = set()
+    for dot in ctx:
+        dot = as_dot(dot)
+        if dot in minted or sc.seen(dot):
+            dead.append(dot)
+        else:
+            minted.add(dot)
+            fresh.append(dot)
+    return sc.add_dots(fresh), ts.add_dots(dead)
 
 
 def clock_key(set_name: bytes) -> bytes:
@@ -388,6 +411,12 @@ class BigsetVnode:
         self.store.on_discard = self._on_discard
         self._discarded: Dict[bytes, List[Dot]] = {}
         self._ts_cache: Dict[bytes, Clock] = {}  # valid only within one compaction
+        # store key -> (stored bytes, decoded clock): a clock is decoded
+        # once per write, not once per read, and an unchanged clock is
+        # written back without re-encoding.  Hits are by identity of the
+        # stored bytes, so any other writer (anti-entropy, recovery)
+        # simply misses.
+        self._clocks: Dict[bytes, Tuple[bytes, Clock]] = {}
         self._indexes: Dict[bytes, Dict[bytes, IndexSpec]] = {}
         # per-set maintained digests of physical element-keys (anti-entropy
         # reads these instead of folding; see SetDigest)
@@ -539,10 +568,32 @@ class BigsetVnode:
 
     # ------------------------------------------------------------- clock io
     def read_clock(self, set_name: bytes) -> Clock:
-        return _clock_from_bytes(self.store.get(clock_key(set_name)))
+        return self._read_clock_at(clock_key(set_name))
 
     def read_tombstone(self, set_name: bytes) -> Clock:
-        return _clock_from_bytes(self.store.get(tombstone_key(set_name)))
+        return self._read_clock_at(tombstone_key(set_name))
+
+    def _read_clock_at(self, key: bytes) -> Clock:
+        raw = self.store.get(key)
+        hit = self._clocks.get(key)
+        if hit is not None and hit[0] is raw:
+            return hit[1]
+        clock = _clock_from_bytes(raw)
+        if raw is not None:
+            self._clocks[key] = (raw, clock)
+        return clock
+
+    def _clock_writes(self, set_name: bytes, sc: Clock,
+                      ts: Clock) -> List[Tuple[bytes, bytes]]:
+        """``[(key, bytes)]`` writing the set-clock and the tombstone."""
+        out = []
+        for key, clock in ((clock_key(set_name), sc),
+                           (tombstone_key(set_name), ts)):
+            hit = self._clocks.get(key)
+            if hit is None or hit[1] is not clock:
+                hit = self._clocks[key] = (_clock_to_bytes(clock), clock)
+            out.append((key, hit[0]))
+        return out
 
     # ----------------------------------------------------------- Algorithm 1
     def coordinate_insert(
@@ -558,21 +609,13 @@ class BigsetVnode:
         returns the delta to send downstream.
         """
         ctx = tuple(ctx)
-        sc = self.read_clock(set_name)
-        ts = self.read_tombstone(set_name)
-        for dot in ctx:
-            if not sc.seen(dot):
-                sc = sc.add(dot)
-            else:
-                ts = ts.add(dot)
+        sc, ts = _absorb_ctx(self.read_clock(set_name),
+                             self.read_tombstone(set_name), ctx)
         sc, dot = sc.increment(self.actor)
         dig = self._digest(set_name)  # adopt pre-state before the key lands
         self.store.put_batch(
-            [
-                (clock_key(set_name), _clock_to_bytes(sc)),
-                (tombstone_key(set_name), _clock_to_bytes(ts)),
-                (element_key(set_name, element, dot), value),
-            ]
+            self._clock_writes(set_name, sc, ts)
+            + [(element_key(set_name, element, dot), value)]
             + self._posting_writes(set_name, element, dot, value)
         )
         self._digest_add(dig, set_name, element, dot)
@@ -586,22 +629,16 @@ class BigsetVnode:
         Returns True if the element-key was written (False -> duplicate no-op).
         """
         set_name = delta.set_name
-        sc0 = sc = self.read_clock(set_name)
-        ts0 = ts = self.read_tombstone(set_name)
-        for dot in delta.ctx:
-            if not sc.seen(dot):
-                sc = sc.add(dot)
-            else:
-                ts = ts.add(dot)
+        sc0 = self.read_clock(set_name)
+        ts0 = self.read_tombstone(set_name)
+        sc, ts = _absorb_ctx(sc0, ts0, delta.ctx)
         if not sc.seen(delta.dot):
             sc = sc.add(delta.dot)
             dig = self._digest(set_name)  # adopt pre-state before the write
             self.store.put_batch(
-                [
-                    (clock_key(set_name), _clock_to_bytes(sc)),
-                    (tombstone_key(set_name), _clock_to_bytes(ts)),
-                    (element_key(set_name, delta.element, delta.dot), delta.value),
-                ]
+                self._clock_writes(set_name, sc, ts)
+                + [(element_key(set_name, delta.element, delta.dot),
+                    delta.value)]
                 + self._posting_writes(
                     set_name, delta.element, delta.dot, delta.value)
             )
@@ -612,12 +649,7 @@ class BigsetVnode:
         # under at-least-once delivery (Clock.add returns self on no-ops,
         # so identity is an exact change test)
         if sc is not sc0 or ts is not ts0:
-            self.store.put_batch(
-                [
-                    (clock_key(set_name), _clock_to_bytes(sc)),
-                    (tombstone_key(set_name), _clock_to_bytes(ts)),
-                ]
-            )
+            self.store.put_batch(self._clock_writes(set_name, sc, ts))
         return False
 
     # -------------------------------------------------------------- removes
@@ -633,21 +665,12 @@ class BigsetVnode:
         self._apply_remove(delta.set_name, delta.ctx)
 
     def _apply_remove(self, set_name: bytes, ctx: Tuple[Dot, ...]) -> None:
-        sc0 = sc = self.read_clock(set_name)
-        ts0 = ts = self.read_tombstone(set_name)
-        for dot in ctx:
-            if sc.seen(dot):
-                ts = ts.add(dot)  # key exists (or existed): compact it away
-            else:
-                sc = sc.add(dot)  # unseen add: pre-empt it ever materialising
+        sc0 = self.read_clock(set_name)
+        ts0 = self.read_tombstone(set_name)
+        sc, ts = _absorb_ctx(sc0, ts0, ctx)
         if sc is sc0 and ts is ts0:
             return  # redelivered remove already absorbed: zero writes
-        self.store.put_batch(
-            [
-                (clock_key(set_name), _clock_to_bytes(sc)),
-                (tombstone_key(set_name), _clock_to_bytes(ts)),
-            ]
-        )
+        self.store.put_batch(self._clock_writes(set_name, sc, ts))
 
     # ---------------------------------------------------------------- reads
     def fold(

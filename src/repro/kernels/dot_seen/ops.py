@@ -1,10 +1,12 @@
 """Jit'd public wrapper for the dot-seen kernel.
 
-Dispatch: Pallas (interpret on CPU, compiled on TPU) or the pure-jnp
-reference.  The bigset read fold and delta-batch dedup call this with the
-tombstone / set-clock in dense *interval* form: per-actor ``(lo, hi)`` run
-arrays (``DenseClock.starts`` / ``.ends``), O(interval runs) with no
-window cap.
+Dispatch is chosen by backend, once per process: on a ``tpu`` backend the
+compiled Pallas kernel, anywhere else the pure-jnp reference.  Tests that
+want the Pallas interpreter ask for it (``use_pallas=True,
+interpret=True``).  The bigset read fold and delta-batch dedup call this
+with the tombstone / set-clock in dense *interval* form: per-actor
+``(lo, hi)`` run arrays (``DenseClock.starts`` / ``.ends``), O(interval
+runs) with no window cap.
 
 Every call is tallied in the process-wide :data:`DISPATCHES` ledger
 (launch count + rows dispatched, padding included).  That ledger is the
@@ -17,6 +19,7 @@ launches/query; the metrics registry lifts it via
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 
 import jax
@@ -34,6 +37,7 @@ class DispatchStats:
     launches: int = 0       # dot_seen invocations (one device dispatch each)
     rows: int = 0           # total rows dispatched, padding included
     pallas_launches: int = 0  # subset of launches routed to the Pallas kernel
+    interpreted: int = 0    # subset of pallas_launches run by the interpreter
 
     def snapshot(self) -> "DispatchStats":
         return DispatchStats(**vars(self))
@@ -46,12 +50,18 @@ class DispatchStats:
 DISPATCHES = DispatchStats()
 
 
+@functools.cache
+def on_tpu() -> bool:
+    """Does this process dispatch to a TPU?  (Decided once, on first use.)"""
+    return jax.default_backend() == "tpu"
+
+
 def dot_seen(
     clock: DenseClock,
     actors: jax.Array,
     counters: jax.Array,
     *,
-    use_pallas: bool = False,
+    use_pallas: bool | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
     """bool[N] — which dots has ``clock`` seen?"""
@@ -59,10 +69,13 @@ def dot_seen(
     counters = jnp.asarray(counters, jnp.int32)
     DISPATCHES.launches += 1
     DISPATCHES.rows += int(actors.shape[0])
+    if use_pallas is None:
+        use_pallas = on_tpu()
     if use_pallas:
-        DISPATCHES.pallas_launches += 1
         if interpret is None:
-            interpret = jax.default_backend() != "tpu"
+            interpret = not on_tpu()
+        DISPATCHES.pallas_launches += 1
+        DISPATCHES.interpreted += int(interpret)
         return dot_seen_pallas(
             clock.starts, clock.ends, actors, counters, interpret=interpret
         )

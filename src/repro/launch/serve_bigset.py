@@ -24,6 +24,7 @@ from ..obs.trace import Tracer
 from ..query.plan import Count, Scan
 from ..serve.bigset_service import (Backpressure, BigsetClient, BigsetService,
                                     ServiceConfig)
+from .compile_cache import enable_compile_cache
 
 SET = b"demo"
 
@@ -46,6 +47,7 @@ def main(argv=None):
                          "file (load in chrome://tracing / Perfetto)")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     tracer = Tracer() if args.trace_out else None
     cluster = BigsetCluster(args.replicas, tracer=tracer)
     service = BigsetService(cluster)  # default config: generous budget

@@ -14,24 +14,50 @@ O(interval runs) — causal metadata — with **no window cap**: the old
 bitmap form had to fall back to scalar probes beyond a fixed per-actor
 spread, but a run covers any span at constant cost.  Dots by actors the
 tombstone has never heard of are unseen by definition and route to the
-sentinel counter ``0``, which no 1-based run can contain.  Batch shapes
-are padded to a fixed bucket so jit traces a handful of shapes, not one
-per chunk length.
+sentinel counter ``0``, which no 1-based run can contain.  Every shape
+is bucketed so jit compiles a handful of programs, not one per chunk
+length or per remove: batches pad to a multiple of :data:`PAD_BUCKET`,
+actors to a multiple of :data:`ACTOR_BUCKET`, and runs to a power of two
+no narrower than the kernel's run tile.
 """
 from __future__ import annotations
 
-from typing import Dict, Optional, Sequence
+from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
 from ..core.clock import Clock
 from ..core.dots import Dot
 from ..core.vclock import from_clock
+from ..kernels.dot_seen.kernel import RUN_TILE
 
 # Chunks smaller than this aren't worth a device dispatch.
 MIN_BATCH = 32
 # Pad batches up to a multiple of this so jit sees few distinct shapes.
 PAD_BUCKET = 512
+# Pad the actor axis to a multiple of this (the TPU's sublane count).
+ACTOR_BUCKET = 8
+# The kernel gathers counters through f32, which is exact below 2**24.
+MAX_COUNTER = 2**24 - 1
+
+
+def dense_shape(tombstone: Clock) -> Tuple[int, int]:
+    """``(actors, widest row's runs)`` of a tombstone, before bucketing."""
+    rows: Dict[object, int] = {}
+    for a, _lo, _hi in tombstone.iter_runs():
+        rows[a] = rows.get(a, 0) + 1
+    return len(rows), max(rows.values(), default=0)
+
+
+def bucket_shape(n_actors: int, n_runs: int) -> Tuple[int, int]:
+    """The ``(A, R)`` the kernel is compiled for: A up to a multiple of
+    :data:`ACTOR_BUCKET`, R up to a power of two of at least
+    :data:`RUN_TILE` — a growing tombstone recompiles once per doubling."""
+    a = -(-max(n_actors, 1) // ACTOR_BUCKET) * ACTOR_BUCKET
+    r = RUN_TILE
+    while r < n_runs:
+        r *= 2
+    return a, r
 
 
 class BatchVisibility:
@@ -41,7 +67,7 @@ class BatchVisibility:
         self,
         tombstone: Clock,
         *,
-        use_pallas: bool = False,
+        use_pallas: Optional[bool] = None,
         interpret: Optional[bool] = None,
         min_batch: int = MIN_BATCH,
         stats=None,
@@ -53,7 +79,7 @@ class BatchVisibility:
         # per-query launch accounting (QueryStats.kernel_launches/_rows):
         # the cross-query micro-batcher's per-query baseline
         self.stats = stats
-        self._dense = None
+        self.dense = None  # the tombstone as the kernel sees it (bucketed)
         self._actor_index: Dict[object, int] = {}
         # counters are 1-based, so 0 is unseen by every run — the routing
         # target for padding and for actors the tombstone never heard of
@@ -65,7 +91,9 @@ class BatchVisibility:
         self._mode = "dense"
         actors = sorted(tombstone.actors(), key=repr)
         self._actor_index = {a: i for i, a in enumerate(actors)}
-        self._dense = from_clock(tombstone, self._actor_index, len(actors))
+        n_actors, n_runs = bucket_shape(*dense_shape(tombstone))
+        self.dense = from_clock(
+            tombstone, self._actor_index, n_actors, n_runs=n_runs)
 
     # ------------------------------------------------------------------ api
     def seen_mask(self, dots: Sequence[Dot]) -> np.ndarray:
@@ -89,6 +117,10 @@ class BatchVisibility:
                 actors[i] = 0
                 counters[i] = self._sentinel
             else:
+                if d.counter > MAX_COUNTER:
+                    raise ValueError(
+                        f"dot counter {d.counter} exceeds {MAX_COUNTER}, "
+                        "the largest the kernel's f32 gather holds exactly")
                 actors[i] = j
                 counters[i] = d.counter
         pad = (-n) % PAD_BUCKET
@@ -102,7 +134,7 @@ class BatchVisibility:
         from ..kernels.dot_seen import dot_seen
 
         mask = dot_seen(
-            self._dense, actors, counters,
+            self.dense, actors, counters,
             use_pallas=self.use_pallas, interpret=self.interpret,
         )
         return np.asarray(mask)[:n]

@@ -286,7 +286,7 @@ class QueryExecutor:
         vnode: BigsetVnode,
         *,
         batch_size: int = DEFAULT_BATCH_SIZE,
-        use_pallas: bool = False,
+        use_pallas: Optional[bool] = None,
         interpret: Optional[bool] = None,
     ):
         self.vnode = vnode

@@ -151,7 +151,7 @@ class DurableMedia:
             return
         cp = self._crash
         if cp is not None and cp.wal_bytes is not None \
-                and len(self.wal) + len(self._buffer) >= cp.wal_bytes:
+                and len(self.wal) + len(self._buffer) > cp.wal_bytes:
             keep = max(cp.wal_bytes - len(self.wal), 0)
             self.wal.extend(self._buffer[:keep])
             raise CrashError(

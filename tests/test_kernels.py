@@ -13,7 +13,10 @@ from repro.core.dots import Dot
 from repro.kernels.decode_attention import decode_attention_pallas, decode_attention_ref
 from repro.kernels.dot_seen import dot_seen_pallas, dot_seen_ref
 from repro.kernels.flash_attention import attention_ref, flash_attention_pallas
+from repro.kernels.dot_seen.kernel import RUN_TILE
 from repro.kernels.mamba_scan import mamba_scan_pallas, mamba_scan_ref, mamba_step_ref
+from repro.query.batch import (ACTOR_BUCKET, MAX_COUNTER, BatchVisibility,
+                               bucket_shape, dense_shape)
 
 RNG = np.random.default_rng(0)
 
@@ -126,7 +129,7 @@ class TestDotSeenKernel:
         actors = jnp.asarray(RNG.integers(0, n_actors, n_dots), jnp.int32)
         counters = jnp.asarray(RNG.integers(1, n_runs * 40 + 80, n_dots), jnp.int32)
         got = dot_seen_pallas(dense.starts, dense.ends, actors, counters,
-                              block_n=block_n)
+                              block_n=block_n, interpret=True)
         want = dot_seen_ref(dense.starts, dense.ends, actors, counters)
         assert (np.asarray(got) == np.asarray(want)).all()
         oracle = np.array([sparse.seen(Dot(names[int(a)], int(c)))
@@ -139,8 +142,86 @@ class TestDotSeenKernel:
         ends = jnp.array([[100, 128], [16_000_000, 0]], jnp.int32)
         actors = jnp.array([0, 0, 0, 1, 1], jnp.int32)
         counters = jnp.array([128, 127, 101, 16_000_000, 16_000_001], jnp.int32)
-        got = dot_seen_pallas(starts, ends, actors, counters, block_n=32)
+        got = dot_seen_pallas(starts, ends, actors, counters, block_n=32,
+                              interpret=True)
         assert np.asarray(got).tolist() == [True, False, False, True, False]
+
+    @pytest.mark.parametrize("n_actors,n_runs", [
+        (1, 2000),   # one actor, R 2000 -> 2048: four run tiles, all in use
+        (3, 1100),   # A 3 -> 8 padded rows, R 1100 -> 2048
+        (5, 520),    # just past one tile: R 520 -> 1024
+    ])
+    def test_bucketed_matches_ref(self, n_actors, n_runs):
+        # The widest row holds the odd counters 1, 3, ..., one run each, so
+        # every probe's answer hangs on a single run column.
+        names = [f"v{i}" for i in range(n_actors)]
+        rng = np.random.default_rng(n_actors * 10_000 + n_runs)
+        sparse = Clock.zero().add_dots(
+            [Dot(names[0], 2 * k + 1) for k in range(n_runs)]
+            + [Dot(names[int(a)], int(c)) for a, c in zip(
+                rng.integers(1, max(n_actors, 2), 200),
+                rng.integers(1, 4000, 200)) if a < n_actors])
+        shape = dense_shape(sparse)
+        assert shape == (n_actors, n_runs)
+        a_pad, r_pad = bucket_shape(*shape)
+        assert a_pad % ACTOR_BUCKET == 0 and r_pad % RUN_TILE == 0
+        assert r_pad >= n_runs > r_pad // 2
+        dense = vclock.from_clock(sparse, {a: i for i, a in enumerate(names)},
+                                  a_pad, n_runs=r_pad)
+        assert dense.starts.shape == (a_pad, r_pad)
+
+        top = 2 * n_runs + 1   # the widest row's last run, then one past it
+        probe_a = np.concatenate([np.zeros(4, np.int64),
+                                  rng.integers(0, n_actors, 1500)])
+        probe_c = np.concatenate([[top - 2, top - 1, top, 1],
+                                  rng.integers(1, top + 2, 1500)])
+        actors = jnp.asarray(probe_a, jnp.int32)
+        counters = jnp.asarray(probe_c, jnp.int32)
+        got = np.asarray(dot_seen_pallas(dense.starts, dense.ends, actors,
+                                         counters, interpret=True))
+        want = np.asarray(dot_seen_ref(dense.starts, dense.ends, actors,
+                                       counters))
+        oracle = np.array([sparse.seen(Dot(names[int(a)], int(c)))
+                           for a, c in zip(probe_a, probe_c)])
+        assert (got == want).all() and (got == oracle).all()
+        assert got[:4].tolist() == [True, False, False, True]
+
+    @pytest.mark.parametrize("col", [0, RUN_TILE - 1, RUN_TILE, 4 * RUN_TILE - 1])
+    def test_single_run_found_in_its_tile_only(self, col):
+        # One run in an otherwise empty (8, 4 tiles) clock: the tile holding
+        # it must OR its hit into the output, and no other tile may clear it.
+        starts = np.ones((ACTOR_BUCKET, 4 * RUN_TILE), np.int32)
+        ends = np.zeros_like(starts)
+        starts[2, col], ends[2, col] = 5_000, 5_002
+        actors = jnp.array([2, 2, 2, 2, 2, 0, 7], jnp.int32)
+        counters = jnp.array([4_999, 5_000, 5_001, 5_002, 5_003, 5_000, 5_000],
+                             jnp.int32)
+        got = dot_seen_pallas(jnp.asarray(starts), jnp.asarray(ends), actors,
+                              counters, block_n=512, interpret=True)
+        assert np.asarray(got).tolist() == [False, True, True, True, False,
+                                            False, False]
+
+    @pytest.mark.parametrize("counter,raises", [
+        (MAX_COUNTER - 1, False),
+        (MAX_COUNTER, False),
+        (MAX_COUNTER + 1, True),
+        (2**30, True),
+    ])
+    @pytest.mark.parametrize("use_pallas", [False, True])
+    def test_batch_visibility_counter_bound(self, counter, raises, use_pallas):
+        # Counters travel through the kernel's f32 gather, exact below 2**24:
+        # a larger one must raise where it is packed, never be misjudged.
+        ts = Clock.zero().add_dots(
+            [Dot("a", MAX_COUNTER), Dot("a", 7), Dot("b", 3)])
+        vis = BatchVisibility(ts, use_pallas=use_pallas, interpret=True,
+                              min_batch=1)
+        dots = [Dot("a", counter), Dot("a", 7), Dot("b", 4)]
+        if raises:
+            with pytest.raises(ValueError, match="exceeds"):
+                vis.seen_mask(dots)
+        else:
+            assert vis.seen_mask(dots).tolist() == [
+                counter == MAX_COUNTER, True, False]
 
 
 # ------------------------------------------------------------------ clock_ops

@@ -201,6 +201,7 @@ class TestPartitionedEquivalence:
         part.settle()
         for _ in range(40):
             part.tick()
+            part.settle()  # an async cluster only queues the tick's pulls
         truth = oracle.query(Range(S), repair=False)
         got = part.query(Range(S), repair=False)
         assert got.members == truth.members

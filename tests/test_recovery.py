@@ -161,6 +161,32 @@ class TestDurableStore:
             expected = b"v%d" % i if i < acked else None
             assert recovered.get(key(i)) == expected
 
+    @pytest.mark.parametrize("k", [1, 2, 13])
+    def test_crash_at_a_record_boundary_keeps_the_whole_record(self, k):
+        """A kill point exactly at the end of record k lets that fsync
+        finish: record k is acknowledged, the next fsync dies with nothing
+        past the boundary, and replay restores exactly k batches."""
+        probe = DurableMedia()
+        store = LsmStore(media=probe, group_depth=1)
+        ends = []
+        for i in range(k + 1):
+            store.put(key(i), b"v%d" % i)
+            ends.append(len(probe.wal))
+        media = DurableMedia()
+        media.schedule_crash(CrashPoint(wal_bytes=ends[k - 1]))
+        store = LsmStore(media=media, group_depth=1)
+        for i in range(k):
+            store.put(key(i), b"v%d" % i)
+        assert store.commit_seq == k
+        with pytest.raises(CrashError):
+            store.put(key(k), b"v%d" % k)
+        assert len(media.wal) == ends[k - 1]
+        media.crash()
+        recovered, res = fresh_recover(media)
+        assert res.batches_replayed == k and res.torn_bytes == 0
+        assert recovered.get(key(k - 1)) == b"v%d" % (k - 1)
+        assert recovered.get(key(k)) is None
+
     def test_empty_wal_recovers_to_an_empty_store(self):
         store, res = fresh_recover(DurableMedia())
         assert res.batches_replayed == res.batches_skipped == 0

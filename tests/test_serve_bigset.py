@@ -21,6 +21,7 @@ from hypothesis import given, settings, strategies as st
 from repro.cluster.clusters import BigsetCluster
 from repro.core.bigset import BigsetVnode
 from repro.index import by_element_suffix
+from repro.obs.trace import Tracer
 from repro.query import (Count, IndexLookup, IndexRange, Join, LeaseError,
                          Membership, PlanError, Range, Scan, plan_from_wire,
                          plan_to_wire, unwrap_lease, wrap_lease)
@@ -364,6 +365,43 @@ class TestWritePath:
         assert results[2]["removed"] is True
         assert results[3]["removed"] is False
         assert cluster.value(S, r=3) == {b"keep"}
+
+    def test_batch_removes_share_one_clock_write(self):
+        tracer = Tracer()
+        cluster = BigsetCluster(3, tracer=tracer)
+        client = BigsetClient(BigsetService(cluster))
+        dots = client.batch(S, [["add", el] for el in ELEMS])
+        tracer.drain()
+        results = client.batch(
+            S, [["remove", el] for el in ELEMS[:6]]
+            + [["remove", ELEMS[0]], ["remove", b"never-there"]])
+        # one remove per op on the wire, each with its own context ...
+        assert [r["removed"] for r in results] == [True] * 6 + [False, False]
+        assert [r["ctx"] for r in results[:6]] == [[d["dot"]] for d in dots[:6]]
+        # ... but the six probed removes reached the replicas as one write
+        assert sum(s.name == "cluster.remove" for s in tracer.spans) == 1
+        for actor in cluster.actors:
+            assert cluster.vnodes[actor].value(S) == set(ELEMS[6:])
+
+    @pytest.mark.parametrize("seed", [0, 1, 2])
+    def test_batch_matches_ops_one_at_a_time(self, seed):
+        # The same mixed batch, sent whole and op by op, answers and ends
+        # in the same state on every replica.
+        import random
+        rnd = random.Random(seed)
+        ops = [[rnd.choice(["add", "remove", "remove"]), rnd.choice(ELEMS)]
+               for _ in range(60)]
+        prefix = [["add", el] for el in ELEMS]
+        whole, _, whole_client, _ = make_service()
+        single, _, single_client, _ = make_service()
+        got = whole_client.batch(S, prefix + ops)
+        want = [r for op in prefix + ops for r in single_client.batch(S, [op])]
+        assert got == want
+        for actor in whole.actors:
+            a, b = whole.vnodes[actor], single.vnodes[actor]
+            assert a.value(S) == b.value(S)
+            assert a.read_clock(S) == b.read_clock(S)
+            assert a.read_tombstone(S) == b.read_tombstone(S)
 
     def test_values_ride_inserts(self):
         cluster, _, client, _ = make_service()

@@ -722,11 +722,6 @@ class BigsetCluster(_ClusterBase):
         with tr.span("cluster.query", plan=type(plan).__name__,
                      set_name=getattr(plan, "set_name", b""), r=r) as qspan:
             meters = [self.vnodes[a].store.meter() for a in vnode_order]
-            # coverage sub-spans opened per covered replica BEFORE execution
-            # (their storage children get the replica's IoStats delta after)
-            rspans = ([tr.start("replica.coverage", parent=qspan.context(),
-                                actor=a) for a in vnode_order]
-                      if tr.enabled else None)
             if isinstance(plan, query_plan.Membership):
                 res = self._q_membership(plan, covers[0], repair)
             elif isinstance(plan, query_plan.Range):
@@ -752,33 +747,27 @@ class BigsetCluster(_ClusterBase):
                 f"epoch={ring.epoch};"
                 f"partitions={sum(len(c.assignments) for c in covers)};"
                 f"vnodes={len(vnode_order)};r={r}")
-            for i, m in enumerate(meters):
+            for m in meters:
                 io = m.delta()
                 res.stats.bytes_read += io.bytes_read
                 res.stats.num_seeks += io.num_seeks
-                if rspans is not None:
-                    rspan = rspans[i]
-                    tr.finish(tr.start(
-                        "storage.scan", parent=rspan.context(),
-                        bytes_read=io.bytes_read, num_seeks=io.num_seeks))
-                    tr.finish(rspan.set(bytes_read=io.bytes_read,
-                                        num_seeks=io.num_seeks))
             account_emitted(res)
-            if tr.enabled:
-                # one summary span for the query's batched-visibility work:
-                # the per-query half of the kernel-launch baseline
-                tr.finish(tr.start(
-                    "kernel.dot_seen", parent=qspan.context(),
-                    launches=res.stats.kernel_launches,
-                    rows=res.stats.kernel_rows))
-                qspan.set(elements=res.stats.elements_emitted,
-                          bytes_read=res.stats.bytes_read)
+            qspan.set(elements=res.stats.elements_emitted,
+                      bytes_read=res.stats.bytes_read,
+                      num_seeks=res.stats.num_seeks,
+                      kernel_launches=res.stats.kernel_launches)
         if session is not None:
             session.observe_query(plan, res)
         return res
 
     def _executors(self, actors) -> List[QueryExecutor]:
-        return [QueryExecutor(self.vnodes[a]) for a in actors]
+        return [QueryExecutor(self.vnodes[a], tracer=self.tracer)
+                for a in actors]
+
+    def _read_clocks(self, set_name: bytes, actors) -> List[Clock]:
+        """Each replica's set clock, for a quorum merge."""
+        with self.tracer.span("storage.read_clock"):
+            return [self.vnodes[a].read_clock(set_name) for a in actors]
 
     def _repair(self, set_name: bytes, element: bytes, dots, per_stream,
                 clocks, actors) -> None:
@@ -850,7 +839,8 @@ class BigsetCluster(_ClusterBase):
         res_stats = QueryStats(
             keys_scanned=sum(p.stats.keys_scanned for p in probes),
             batches=sum(p.stats.batches for p in probes),
-            keys_probed=sum(p.stats.keys_probed for p in probes))
+            keys_probed=sum(p.stats.keys_probed for p in probes),
+            tombstone_runs=sum(p.stats.tombstone_runs for p in probes))
         per_stream = [
             frozenset(p.entries[0][1]) if p.present else None for p in probes
         ]
@@ -893,7 +883,7 @@ class BigsetCluster(_ClusterBase):
                             stats=stats)
             for ex in self._executors(actors)
         ]
-        clocks = [self.vnodes[a].read_clock(set_name) for a in actors]
+        clocks = self._read_clocks(set_name, actors)
         repair_fn = (
             (lambda el, dots, per: self._repair(
                 set_name, el, dots, per, clocks, actors))
@@ -931,7 +921,7 @@ class BigsetCluster(_ClusterBase):
                             at=at, after=after, stats=res.stats)
             for ex in self._executors(actors)
         ]
-        clocks = [self.vnodes[a].read_clock(pset) for a in actors]
+        clocks = self._read_clocks(pset, actors)
         repair_fn = (
             (lambda pos, dots, per: self._repair(
                 pset, pos[1], dots, per, clocks, actors))
@@ -1067,7 +1057,7 @@ class BigsetCluster(_ClusterBase):
         surviving dot — the membership path's semantics, packaged as the
         gallop join's larger-side primitive.
         """
-        clocks = [self.vnodes[a].read_clock(set_name) for a in actors]
+        clocks = self._read_clocks(set_name, actors)
         probes = [
             ex.element_probe(set_name, stats) for ex in self._executors(actors)
         ]

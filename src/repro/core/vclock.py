@@ -276,6 +276,8 @@ def from_clock(
 
     ``n_runs`` pads the run axis to a fixed width (for shape-stable jit);
     defaults to the widest row.  Raises if a row needs more than ``n_runs``.
+    The arrays are host ``numpy`` arrays: the caller decides when and
+    where they are copied to a device (every op here also takes them).
     """
     rows: Dict[int, list] = {}
     for a, lo, hi in clock.iter_runs():
@@ -291,7 +293,7 @@ def from_clock(
         for k, (lo, hi) in enumerate(rs):
             starts[i, k] = lo
             ends[i, k] = hi
-    return DenseClock(jnp.asarray(starts), jnp.asarray(ends))
+    return DenseClock(starts, ends)
 
 
 def to_clock(clock: DenseClock, actors: Sequence[object]) -> Clock:

@@ -103,5 +103,6 @@ def dot_seen_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="dot_seen",  # a stable name in device traces
     )(starts, ends, actors, counters)
     return out[:n].astype(bool)

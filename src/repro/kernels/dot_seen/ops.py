@@ -13,9 +13,10 @@ Every call is tallied in the process-wide :data:`DISPATCHES` ledger
 measured baseline for the ROADMAP cross-query micro-batcher: today 1000
 concurrent small queries pay 1000 launches over tiny arrays, and the only
 honest way to claim a coalescer wins is to watch ``launches`` fall while
-``rows`` holds.  ``benchmarks/bench_serve.py`` reports it as amortized
-launches/query; the metrics registry lifts it via
-:func:`repro.obs.metrics.lift_dispatch_stats`.
+``rows`` holds.  The chip benchmark reads its window deltas as
+``launches_per_page`` and ``useful_row_pct`` (``bench/metrics/``); the
+metrics registry lifts it via
+:func:`repro.obs.metrics.lift_dispatch_stats` for the ``stats`` op.
 """
 from __future__ import annotations
 

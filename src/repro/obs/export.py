@@ -1,15 +1,13 @@
-"""Span exporters: JSONL dumps and Chrome trace-event files.
+"""Span exporter: Chrome trace-event files, and span-tree views.
 
-Two formats, one source (:attr:`repro.obs.trace.Tracer.spans`):
-
-* **JSONL** — one span per line, machine-greppable, append-friendly; the
-  format CI artifacts and offline analysis consume.
-* **Chrome trace-event** — ``{"traceEvents": [...]}`` of complete
-  (``"ph": "X"``) events, loadable in ``chrome://tracing`` / Perfetto for
-  flamegraph viewing.  Each event carries ``span_id`` / ``parent_id`` /
-  ``trace_id`` in ``args`` so the span *tree* round-trips through the
-  format, not just the timings — the CI smoke job re-parses an exported
-  file and checks every parent resolves.
+One source (:attr:`repro.obs.trace.Tracer.spans`), one format:
+``{"traceEvents": [...]}`` of complete (``"ph": "X"``) events, loadable in
+``chrome://tracing`` / Perfetto for flamegraph viewing
+(``serve_bigset --trace-out``).  Each event carries ``span_id`` /
+``parent_id`` / ``trace_id`` in ``args`` so the span *tree* round-trips
+through the format, not just the timings — the CI smoke job re-parses an
+exported file and checks every parent resolves.  A tracer in profiler
+mode keeps no spans: its spans are in the profiler's trace instead.
 """
 from __future__ import annotations
 
@@ -19,18 +17,6 @@ from typing import Dict, Iterable, List, Optional
 from .trace import Span
 
 
-def span_to_dict(span: Span) -> dict:
-    return {
-        "name": span.name,
-        "trace_id": span.trace_id,
-        "span_id": span.span_id,
-        "parent_id": span.parent_id,
-        "start": span.start,
-        "end": span.end,
-        "attrs": {k: _jsonable(v) for k, v in span.attrs.items()},
-    }
-
-
 def _jsonable(v):
     """Span attrs may hold bytes (set names, elements): make them JSON-safe."""
     if isinstance(v, bytes):
@@ -38,17 +24,6 @@ def _jsonable(v):
     if isinstance(v, (list, tuple)):
         return [_jsonable(x) for x in v]
     return v
-
-
-# --------------------------------------------------------------------- jsonl
-def spans_to_jsonl(spans: Iterable[Span]) -> str:
-    return "".join(json.dumps(span_to_dict(s), sort_keys=True) + "\n"
-                   for s in spans)
-
-
-def write_jsonl(spans: Iterable[Span], path: str) -> None:
-    with open(path, "w") as f:
-        f.write(spans_to_jsonl(spans))
 
 
 # -------------------------------------------------------------- chrome trace

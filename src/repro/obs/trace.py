@@ -15,7 +15,18 @@ Design constraints, in order:
   :data:`NULL_TRACER`: every instrumentation point degrades to a cheap
   no-op, and — critically — network payloads are *never* wrapped, so the
   bytes a disabled cluster ships are byte-identical to the pre-tracing
-  code (asserted in ``tests/test_obs.py``).
+  code (asserted in ``tests/test_obs.py``).  Points inside a per-chunk or
+  per-launch loop test :attr:`Tracer.enabled` first and enter
+  :data:`NO_SPAN` when it is false.
+* **One clock with the device.**  ``Tracer(profiler=True)`` opens a
+  ``jax.profiler.TraceAnnotation`` for exactly each span's lifetime, so
+  the spans land on the host plane of a profiler trace beside the
+  device's operations, on the profiler's clock.  In that mode the
+  profiler's buffer holds the spans: the tracer keeps no :class:`Span`
+  after :meth:`Tracer.finish`, so a long traced window grows no Python
+  heap.  Spans nest on the thread — none stays open across a ``yield``
+  — which is what lets a reader of the trace take each span's self time
+  from the nesting alone.
 * **Deterministic under injected clocks.**  The tracer takes a
   ``clock() -> float`` exactly like the serve layer's lease clock: tests
   drive a fake clock and assert exact span durations.  Span ids are a
@@ -30,7 +41,7 @@ Design constraints, in order:
 from __future__ import annotations
 
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Iterator, List, Optional
 
@@ -52,7 +63,7 @@ class Span:
     """One unit of traced work.  Mutable until :meth:`Tracer.finish`."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "start", "end",
-                 "attrs")
+                 "attrs", "annotation")
 
     def __init__(self, name: str, trace_id: int, span_id: int,
                  parent_id: Optional[int], start: float,
@@ -64,6 +75,7 @@ class Span:
         self.start = start
         self.end: Optional[float] = None
         self.attrs = attrs
+        self.annotation = None  # the open profiler annotation, if mirrored
 
     def context(self) -> TraceContext:
         return TraceContext(self.trace_id, self.span_id)
@@ -84,21 +96,36 @@ class Span:
 
 
 class Tracer:
-    """Span factory + in-memory sink.
+    """Span factory + in-memory sink, or a mirror into the profiler.
 
     ``clock`` is injectable monotonic seconds (the ``bigset_service``
     lease-clock idiom); ids are sequential so tests are exact.  Finished
     spans accumulate in :attr:`spans` until :meth:`clear` / :meth:`drain`
     — exporters (:mod:`repro.obs.export`) read them from there.
+
+    With ``profiler=True`` every span is a ``jax.profiler.TraceAnnotation``
+    (named as the span, with the attributes it was opened with) from its
+    start to its finish, and finished spans are not kept: a profiler trace
+    taken meanwhile holds them.  A :meth:`span` scope is then the
+    annotation alone — no :class:`Span`, id or clock read — and yields the
+    inert span, so a traced request pays about what the profiler's own
+    annotation costs.  Outside an active trace the annotations record
+    nothing.
     """
 
     enabled = True
 
-    def __init__(self, clock: Callable[[], float] = time.monotonic):  # bigset-lint: disable=BS001 -- default for the *injectable* clock; deterministic runs inject a fake (tests/test_obs.py)
+    def __init__(self, clock: Callable[[], float] = time.monotonic,  # bigset-lint: disable=BS001 -- default for the *injectable* clock; deterministic runs inject a fake (tests/test_obs.py)
+                 *, profiler: bool = False):
         self._clock = clock
         self._next_id = 0
         self._stack: List[Span] = []
         self.spans: List[Span] = []
+        self._annotate = None
+        if profiler:
+            import jax
+
+            self._annotate = jax.profiler.TraceAnnotation
 
     # ------------------------------------------------------------ span api
     def current(self) -> Optional[TraceContext]:
@@ -116,18 +143,32 @@ class Tracer:
             trace_id, parent_id = self._next_id, None
         else:
             trace_id, parent_id = parent.trace_id, parent.span_id
-        return Span(name, trace_id, self._next_id, parent_id,
+        span = Span(name, trace_id, self._next_id, parent_id,
                     self._clock(), attrs)
+        if self._annotate is not None:
+            span.annotation = self._annotate(name, **attrs)
+            span.annotation.__enter__()
+        return span
 
     def finish(self, span: Span) -> Span:
         span.end = self._clock()
-        self.spans.append(span)
+        if span.annotation is not None:
+            span.annotation.__exit__(None, None, None)
+            span.annotation = None
+        elif self._annotate is None:
+            self.spans.append(span)
         return span
 
-    @contextmanager
     def span(self, name: str, parent: Optional[TraceContext] = None,
-             **attrs: Any) -> Iterator[Span]:
+             **attrs: Any):
         """Scoped span: children opened inside parent under it implicitly."""
+        if self._annotate is not None:
+            return _Annotated(self._annotate(name, **attrs))
+        return self._scope(name, parent, attrs)
+
+    @contextmanager
+    def _scope(self, name: str, parent: Optional[TraceContext],
+               attrs: Dict[str, Any]) -> Iterator[Span]:
         sp = self.start(name, parent=parent, **attrs)
         self._stack.append(sp)
         try:
@@ -158,12 +199,44 @@ class _NullSpan(Span):
     def set(self, **attrs: Any) -> "Span":
         return self
 
-    def context(self) -> TraceContext:  # pragma: no cover - never parented
+    def context(self) -> TraceContext:
+        # parents only in profiler mode, where the trace keeps no ids
         return TraceContext(0, 0)
 
 
+class _NullScope:
+    """The null tracer's reusable ``span()`` scope: yields the inert span."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> Span:
+        return NullTracer._SPAN
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+class _Annotated:
+    """A profiler-mode ``span()`` scope: the annotation is open for exactly
+    the block, and the block gets the inert span."""
+
+    __slots__ = ("_annotation",)
+
+    def __init__(self, annotation):
+        self._annotation = annotation
+
+    def __enter__(self) -> Span:
+        self._annotation.__enter__()
+        return NullTracer._SPAN
+
+    def __exit__(self, *exc) -> bool:
+        self._annotation.__exit__(*exc)
+        return False
+
+
 class NullTracer(Tracer):
-    """Tracing off: no spans, no ids, no clock reads, no payload wrapping.
+    """Tracing off: no spans, no ids, no clock reads, no payload wrapping,
+    no profiler annotation.
 
     Instrumentation points must ALSO consult :attr:`enabled` before doing
     anything that would alter observable behavior (wrapping a network
@@ -188,10 +261,13 @@ class NullTracer(Tracer):
     def finish(self, span: Span) -> Span:
         return span
 
-    @contextmanager
     def span(self, name: str, parent: Optional[TraceContext] = None,
-             **attrs: Any) -> Iterator[Span]:
-        yield self._SPAN
+             **attrs: Any) -> _NullScope:
+        return _NULL_SCOPE
 
 
+_NULL_SCOPE = _NullScope()
 NULL_TRACER = NullTracer()
+# what a per-chunk instrumentation point enters when tracing is off:
+# ``with tr.span(...) if tr.enabled else NO_SPAN:``
+NO_SPAN = nullcontext()

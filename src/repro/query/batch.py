@@ -19,17 +19,25 @@ is bucketed so jit compiles a handful of programs, not one per chunk
 length or per remove: batches pad to a multiple of :data:`PAD_BUCKET`,
 actors to a multiple of :data:`ACTOR_BUCKET`, and runs to a power of two
 no narrower than the kernel's run tile.
+
+Spans (when the query's tracer is on): ``query.visibility_build`` around
+the rebuild, with ``kernel.h2d`` around the tombstone's copy to the
+device; per chunk ``query.pack`` (dots to arrays) or ``query.host_seen``
+(a chunk under :data:`MIN_BATCH`), ``kernel.dot_seen`` around the launch
+and ``kernel.mask_sync`` while the host waits for the mask.
 """
 from __future__ import annotations
 
 from typing import Dict, Optional, Sequence, Tuple
 
+import jax.numpy as jnp
 import numpy as np
 
 from ..core.clock import Clock
 from ..core.dots import Dot
-from ..core.vclock import from_clock
+from ..core.vclock import DenseClock, from_clock
 from ..kernels.dot_seen.kernel import RUN_TILE
+from ..obs.trace import NO_SPAN, NULL_TRACER, Tracer
 
 # Chunks smaller than this aren't worth a device dispatch.
 MIN_BATCH = 32
@@ -71,14 +79,16 @@ class BatchVisibility:
         interpret: Optional[bool] = None,
         min_batch: int = MIN_BATCH,
         stats=None,
+        tracer: Tracer = NULL_TRACER,
     ):
         self.tombstone = tombstone
         self.use_pallas = use_pallas
         self.interpret = interpret
         self.min_batch = min_batch
-        # per-query launch accounting (QueryStats.kernel_launches/_rows):
-        # the cross-query micro-batcher's per-query baseline
+        # per-query launch accounting (QueryStats.kernel_launches/_rows,
+        # tombstone_runs): the cross-query micro-batcher's baseline
         self.stats = stats
+        self.tracer = tracer
         self.dense = None  # the tombstone as the kernel sees it (bucketed)
         self._actor_index: Dict[object, int] = {}
         # counters are 1-based, so 0 is unseen by every run — the routing
@@ -89,11 +99,17 @@ class BatchVisibility:
             self._mode = "empty"
             return
         self._mode = "dense"
-        actors = sorted(tombstone.actors(), key=repr)
-        self._actor_index = {a: i for i, a in enumerate(actors)}
-        n_actors, n_runs = bucket_shape(*dense_shape(tombstone))
-        self.dense = from_clock(
-            tombstone, self._actor_index, n_actors, n_runs=n_runs)
+        with tracer.span("query.visibility_build"):
+            actors = sorted(tombstone.actors(), key=repr)
+            self._actor_index = {a: i for i, a in enumerate(actors)}
+            n_actors, n_runs = bucket_shape(*dense_shape(tombstone))
+            host = from_clock(
+                tombstone, self._actor_index, n_actors, n_runs=n_runs)
+            with tracer.span("kernel.h2d"):
+                self.dense = DenseClock(
+                    jnp.asarray(host.starts), jnp.asarray(host.ends))
+        if stats is not None:
+            stats.tombstone_runs += tombstone.n_runs()
 
     # ------------------------------------------------------------------ api
     def seen_mask(self, dots: Sequence[Dot]) -> np.ndarray:
@@ -103,9 +119,32 @@ class BatchVisibility:
             return np.zeros((0,), bool)
         if self._mode == "empty":
             return np.zeros((n,), bool)
+        tr = self.tracer
         if n < self.min_batch:
-            ts = self.tombstone
-            return np.fromiter((ts.seen(d) for d in dots), bool, count=n)
+            with tr.span("query.host_seen") if tr.enabled else NO_SPAN:
+                return self.seen_scalar(dots)
+        with tr.span("query.pack") if tr.enabled else NO_SPAN:
+            actors, counters = self._pack(dots)
+        if self.stats is not None:
+            self.stats.kernel_launches += 1
+            self.stats.kernel_rows += n
+        from ..kernels.dot_seen import dot_seen
+
+        # A and R name the compiled shape, N the padded batch
+        with (tr.span("kernel.dot_seen", A=self.dense.n_actors,
+                      R=self.dense.n_runs, N=len(actors))
+              if tr.enabled else NO_SPAN):
+            mask = dot_seen(
+                self.dense, actors, counters,
+                use_pallas=self.use_pallas, interpret=self.interpret,
+            )
+        with tr.span("kernel.mask_sync") if tr.enabled else NO_SPAN:
+            return np.asarray(mask)[:n]
+
+    def _pack(self, dots: Sequence[Dot]) -> Tuple[np.ndarray, np.ndarray]:
+        """The chunk's dots as ``(actors, counters)`` ``int32`` arrays,
+        padded to a multiple of :data:`PAD_BUCKET` with the sentinel."""
+        n = len(dots)
         idx = self._actor_index
         actors = np.empty((n,), np.int32)
         counters = np.empty((n,), np.int32)
@@ -128,16 +167,7 @@ class BatchVisibility:
             actors = np.pad(actors, (0, pad))
             counters = np.pad(
                 counters, (0, pad), constant_values=self._sentinel)
-        if self.stats is not None:
-            self.stats.kernel_launches += 1
-            self.stats.kernel_rows += n
-        from ..kernels.dot_seen import dot_seen
-
-        mask = dot_seen(
-            self.dense, actors, counters,
-            use_pallas=self.use_pallas, interpret=self.interpret,
-        )
-        return np.asarray(mask)[:n]
+        return actors, counters
 
     def seen_scalar(self, dots: Sequence[Dot]) -> np.ndarray:
         """Scalar oracle (for tests / tiny batches)."""

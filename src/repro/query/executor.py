@@ -29,6 +29,12 @@ plan's ``strategy`` field:
 Both emit byte-identical entries; the chosen strategy is reported in
 :attr:`QueryStats.strategy` and flows through the serve layer's per-page
 stats.
+
+With a tracer on, each replica stream is a ``query.stream_open`` span
+(its tombstone read, visibility build, cursor seek and first chunk);
+the executor's calls into the vnode and the LSM are ``storage.read_clock``,
+``storage.seek`` and, per chunk pulled from the raw cursor,
+``storage.scan``.  Chunk spans close before the stream yields.
 """
 from __future__ import annotations
 
@@ -40,6 +46,7 @@ import msgpack
 from ..core.bigset import BigsetVnode
 from ..core.clock import Clock
 from ..core.dots import Dot, DotList
+from ..obs.trace import NO_SPAN, NULL_TRACER, Tracer
 from ..storage.keycodec import successor_bytes
 from .batch import BatchVisibility
 from .cursor import decode_cursor, encode_cursor, resume_point
@@ -67,6 +74,7 @@ class QueryStats:
                            # gallop probes), counted on hits AND misses
     kernel_launches: int = 0  # batched dot_seen dispatches this query paid
     kernel_rows: int = 0      # dots those dispatches covered (pre-padding)
+    tombstone_runs: int = 0   # real tombstone runs densified for the query
     strategy: str = ""     # join strategy the planner executed ("" otherwise)
     coverage: str = ""     # ring coverage the cluster planned for this query
                            # ("epoch=E;partitions=P;vnodes=V;r=R")
@@ -110,12 +118,14 @@ class _EntryStream:
         end: Optional[bytes] = None,
         after: Optional[bytes] = None,
         batch_size: int = DEFAULT_BATCH_SIZE,
+        tracer: Tracer = NULL_TRACER,
     ):
         self._vnode = vnode
         self._set = set_name
         self._vis = vis
         self._stats = stats
         self._batch = batch_size
+        self._tracer = tracer
         # Grow chunks geometrically: a limit-25 page must not pre-pay for a
         # full batch of keys (O(result), not O(batch)); deep scans still
         # amortise into full-width visibility dispatches.
@@ -123,8 +133,9 @@ class _EntryStream:
         # last element the raw cursor has read into a chunk: the boundary
         # between draining already-paid read-ahead and a storage seek
         self._last_raw_el: Optional[bytes] = None
-        self._raw = vnode.element_cursor(
-            set_name, start=start, end=end, after=after)
+        with tracer.span("storage.seek"):
+            self._raw = vnode.element_cursor(
+                set_name, start=start, end=end, after=after)
         self._gen = self._generate()
         self.head: Optional[Tuple[bytes, DotList]] = next(self._gen, None)
 
@@ -149,7 +160,9 @@ class _EntryStream:
             if self._last_raw_el is None or self._last_raw_el >= element:
                 self.advance()
                 continue
-            self._raw.seek(element)
+            tr = self._tracer
+            with tr.span("storage.seek") if tr.enabled else NO_SPAN:
+                self._raw.seek(element)
             self._chunk = SEEK_CHUNK
             self._last_raw_el = None
             self._gen = self._generate()
@@ -158,15 +171,17 @@ class _EntryStream:
 
     def _generate(self) -> Iterator[Tuple[bytes, DotList]]:
         raw = self._raw
+        tr = self._tracer
         cur_el: Optional[bytes] = None
         cur_dots: List[Dot] = []
         while True:
             chunk: List[Tuple[bytes, Dot]] = []
-            for el, dot, _v in raw:
-                chunk.append((el, dot))
-                self._last_raw_el = el
-                if len(chunk) >= self._chunk:
-                    break
+            with tr.span("storage.scan") if tr.enabled else NO_SPAN:
+                for el, dot, _v in raw:
+                    chunk.append((el, dot))
+                    self._last_raw_el = el
+                    if len(chunk) >= self._chunk:
+                        break
             if not chunk:
                 break
             self._chunk = min(self._chunk * 4, self._batch)
@@ -208,6 +223,7 @@ class _IndexStream:
         at: Optional[Tuple[bytes, bytes]] = None,
         after: Optional[Tuple[bytes, bytes]] = None,
         batch_size: int = DEFAULT_BATCH_SIZE,
+        tracer: Tracer = NULL_TRACER,
     ):
         self._vnode = vnode
         self._set = set_name
@@ -216,6 +232,7 @@ class _IndexStream:
         self._stats = stats
         self._end = end
         self._batch = batch_size
+        self._tracer = tracer
         self._gen = self._generate(start=start, at=at, after=after)
         self.head: Optional[Tuple[Tuple[bytes, bytes], DotList]] = next(
             self._gen, None)
@@ -234,15 +251,17 @@ class _IndexStream:
         raw = self._vnode.fold_postings(
             self._set, self._index, start=start, end=self._end,
             at=at, after=after)
+        tr = self._tracer
         cur: Optional[Tuple[bytes, bytes]] = None
         cur_live = False
         chunk_size = min(32, self._batch)
         while True:
             chunk: List[Tuple[bytes, bytes, Dot]] = []
-            for ik, el, dot in raw:
-                chunk.append((ik, el, dot))
-                if len(chunk) >= chunk_size:
-                    break
+            with tr.span("storage.scan") if tr.enabled else NO_SPAN:
+                for ik, el, dot in raw:
+                    chunk.append((ik, el, dot))
+                    if len(chunk) >= chunk_size:
+                        break
             if not chunk:
                 break
             chunk_size = min(chunk_size * 4, self._batch)
@@ -269,10 +288,12 @@ class _IndexStream:
         """Fetch the element's full surviving dots (the ISSUE's "then fetch
         matching elements" step): one bounded seek into the element range."""
         _ik, element = pos
-        dots = [
-            d for _e, d, _v in self._vnode.fold_raw(
-                self._set, start=element, end=successor_bytes(element))
-        ]
+        tr = self._tracer
+        with tr.span("storage.scan") if tr.enabled else NO_SPAN:
+            dots = [
+                d for _e, d, _v in self._vnode.fold_raw(
+                    self._set, start=element, end=successor_bytes(element))
+            ]
         mask = self._vis.seen_mask(dots)
         live = tuple(sorted(d for d, is_dead in zip(dots, mask) if not is_dead))
         return (pos, live) if live else None
@@ -288,11 +309,13 @@ class QueryExecutor:
         batch_size: int = DEFAULT_BATCH_SIZE,
         use_pallas: Optional[bool] = None,
         interpret: Optional[bool] = None,
+        tracer: Tracer = NULL_TRACER,
     ):
         self.vnode = vnode
         self.batch_size = batch_size
         self.use_pallas = use_pallas
         self.interpret = interpret
+        self.tracer = tracer
 
     # ----------------------------------------------------------------- public
     def execute(self, plan: Plan) -> QueryResult:
@@ -330,13 +353,11 @@ class QueryExecutor:
     ) -> _EntryStream:
         """Visible entry stream hook (also driven by the cluster layer)."""
         stats = stats if stats is not None else QueryStats()
-        vis = BatchVisibility(
-            self.vnode.read_tombstone(set_name),
-            use_pallas=self.use_pallas, interpret=self.interpret,
-            stats=stats)
-        return _EntryStream(
-            self.vnode, set_name, vis, stats,
-            start=start, end=end, after=after, batch_size=self.batch_size)
+        with self.tracer.span("query.stream_open"):
+            return _EntryStream(
+                self.vnode, set_name, self._visibility(set_name, stats),
+                stats, start=start, end=end, after=after,
+                batch_size=self.batch_size, tracer=self.tracer)
 
     def index_stream(
         self,
@@ -350,18 +371,29 @@ class QueryExecutor:
     ) -> _IndexStream:
         """Visible posting-group stream (also driven by the cluster layer)."""
         stats = stats if stats is not None else QueryStats()
-        vis = BatchVisibility(
-            self.vnode.read_tombstone(set_name),
-            use_pallas=self.use_pallas, interpret=self.interpret,
-            stats=stats)
-        return _IndexStream(
-            self.vnode, set_name, index_name, vis, stats,
-            start=start, end=end, at=at, after=after,
-            batch_size=self.batch_size)
+        with self.tracer.span("query.stream_open"):
+            return _IndexStream(
+                self.vnode, set_name, index_name,
+                self._visibility(set_name, stats), stats,
+                start=start, end=end, at=at, after=after,
+                batch_size=self.batch_size, tracer=self.tracer)
+
+    def _visibility(self, set_name: bytes,
+                    stats: QueryStats) -> BatchVisibility:
+        """The set's tombstone, read and densified for batched tests."""
+        with self.tracer.span("storage.read_clock"):
+            tombstone = self.vnode.read_tombstone(set_name)
+        return BatchVisibility(
+            tombstone, use_pallas=self.use_pallas, interpret=self.interpret,
+            stats=stats, tracer=self.tracer)
+
+    def _read_clock(self, set_name: bytes) -> Clock:
+        with self.tracer.span("storage.read_clock"):
+            return self.vnode.read_clock(set_name)
 
     # ---------------------------------------------------------------- shapes
     def _membership(self, plan: Membership) -> QueryResult:
-        res = QueryResult(clock=self.vnode.read_clock(plan.set_name))
+        res = QueryResult(clock=self._read_clock(plan.set_name))
         res.stats.keys_probed += 1  # misses must account the probed key too
         stream = self.entry_stream(
             plan.set_name, start=plan.element,
@@ -386,14 +418,14 @@ class QueryExecutor:
         resume_start, after = resume_point(cursor, scope)
         if resume_start is not None:
             start = resume_start
-        res = QueryResult(clock=self.vnode.read_clock(set_name))
+        res = QueryResult(clock=self._read_clock(set_name))
         stream = self.entry_stream(
             set_name, start=start, end=end, after=after, stats=res.stats)
         collect_page(stream_entries(stream), limit, scope, res)
         return res
 
     def _count(self, plan: Count) -> QueryResult:
-        res = QueryResult(clock=self.vnode.read_clock(plan.set_name))
+        res = QueryResult(clock=self._read_clock(plan.set_name))
         stream = self.entry_stream(
             plan.set_name, start=plan.start, end=plan.end, stats=res.stats)
         n = 0
@@ -407,7 +439,7 @@ class QueryExecutor:
         start, end = index_span(plan)
         at, after = index_resume_point(plan.cursor, scope)
         res = QueryResult(
-            clock=self.vnode.read_clock(plan.set_name), index_entries=[])
+            clock=self._read_clock(plan.set_name), index_entries=[])
         if isinstance(plan, IndexLookup):
             res.stats.keys_probed += 1
         stream = self.index_stream(
@@ -420,8 +452,8 @@ class QueryExecutor:
         scope = cursor_scope(plan)
         start, after = resume_point(plan.cursor, scope)
         res = QueryResult(
-            clock=self.vnode.read_clock(plan.left).join(
-                self.vnode.read_clock(plan.right)))
+            clock=self._read_clock(plan.left).join(
+                self._read_clock(plan.right)))
         choice = choose_join(
             plan.kind,
             side_stats(self.vnode.store, plan.left),
@@ -456,18 +488,18 @@ class QueryExecutor:
         ``keys_probed`` on hits AND misses; only the element's own keys
         land in ``keys_scanned``, never the gap galloped over.
         """
-        vis = BatchVisibility(
-            self.vnode.read_tombstone(set_name),
-            use_pallas=self.use_pallas, interpret=self.interpret,
-            stats=stats)
+        with self.tracer.span("query.stream_open"):
+            vis = self._visibility(set_name, stats)
         vnode = self.vnode
+        tr = self.tracer
 
         def probe(element: bytes) -> Optional[DotList]:
             stats.keys_probed += 1
-            dots = [
-                dot for _el, dot, _v in vnode.fold_raw(
-                    set_name, start=element, end=element + b"\x00")
-            ]
+            with tr.span("storage.scan") if tr.enabled else NO_SPAN:
+                dots = [
+                    dot for _el, dot, _v in vnode.fold_raw(
+                        set_name, start=element, end=element + b"\x00")
+                ]
             stats.keys_scanned += len(dots)
             if not dots:
                 return None

@@ -4,30 +4,36 @@ The load-bearing invariants, in test order:
 
 * **One tree per request.**  A traced quorum query yields a single span
   tree rooted at the serve layer covering every downstream layer —
-  coordinator, per-replica coverage, storage, the visibility kernel,
-  network deliveries, read repair — and stays a tree (zero orphans)
-  under drop/duplicate/reorder delivery.
+  coordinator, replica streams, storage, the visibility rebuild and
+  kernel launches, network deliveries, read repair — and stays a tree
+  (zero orphans) under drop/duplicate/reorder delivery.  Every child lies
+  inside its parent, with one span per stream and per launch.
 * **Determinism under injected clocks.**  Fake clocks make span
   durations and histogram contents exact, and two identical runs
   produce identical traces.
 * **Disabled ⇒ zero behavior change.**  The default NULL_TRACER wraps
   no payloads: wire traffic is byte-identical with tracing off
-  (ARCHITECTURE invariant 10), and ``Network.send`` refuses un-billed
-  non-empty payloads so wire accounting cannot silently read zero.
+  (ARCHITECTURE invariant 10), builds no profiler annotation, and
+  ``Network.send`` refuses un-billed non-empty payloads so wire
+  accounting cannot silently read zero.
+* **Profiler mode.**  ``Tracer(profiler=True)`` opens one profiler
+  annotation per span for exactly its lifetime and keeps no spans.
 """
 import json
 
+import jax
 import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.cluster.clusters import BigsetCluster, TracedPayload
 from repro.cluster.sim import Network
-from repro.obs.export import (span_trees, spans_to_chrome, spans_to_jsonl,
-                              tree_names)
+from repro.cluster.placement import Ring
+from repro.obs.export import span_trees, spans_to_chrome, tree_names
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                lift_dispatch_stats, lift_network)
 from repro.obs.trace import NULL_TRACER, TraceContext, Tracer
-from repro.query.plan import Membership, Scan
+from repro.query import executor
+from repro.query.plan import Membership, Range, Scan
 from repro.serve.bigset_service import (BigsetClient, BigsetService,
                                         ServiceConfig)
 
@@ -102,6 +108,39 @@ class TestTracer:
         assert NULL_TRACER.spans == []
         assert not NULL_TRACER.enabled
         assert sp.attrs == {}  # set() was a no-op
+
+    def test_profiler_mode_annotates_each_span_and_keeps_none(
+            self, monkeypatch):
+        events = []
+
+        class Annotation:
+            def __init__(self, name, **attrs):
+                self.name, self.attrs = name, attrs
+
+            def __enter__(self):
+                events.append(("enter", self.name, self.attrs))
+
+            def __exit__(self, *exc):
+                events.append(("exit", self.name, {}))
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+        tr = Tracer(clock=ticking_clock(), profiler=True)
+        with tr.span("outer", n=1) as outer:
+            with tr.span("inner"):
+                pass
+            late = tr.start("remote", parent=outer.context())
+            tr.finish(late)
+            outer.set(ignored=True)  # the block gets the inert span
+        with pytest.raises(ValueError):
+            with tr.span("boom"):
+                raise ValueError("x")
+        assert events == [
+            ("enter", "outer", {"n": 1}), ("enter", "inner", {}),
+            ("exit", "inner", {}), ("enter", "remote", {}),
+            ("exit", "remote", {}), ("exit", "outer", {}),
+            ("enter", "boom", {}), ("exit", "boom", {})]
+        assert tr.spans == []      # the profiler's buffer holds them
+        assert late.end is not None and outer.attrs == {}
 
 
 # ==================================================================== metrics
@@ -182,11 +221,12 @@ class TestTracedQuery:
         read-repair."""
         tr, cluster, service, client = build_traced()
         client.batch(SET, [["add", b"r%02d" % i] for i in range(5)])
+        client.batch(SET, [["remove", b"r00"]])  # a tombstone to rebuild
         diverge(cluster, [b"x%02d" % i for i in range(3)])
         tr.clear()  # keep only the query's spans
 
         page = client.query(Scan(SET, page_size=100))
-        assert len(page.entries) == 8
+        assert len(page.entries) == 7
 
         spans = tr.drain()
         trees = span_trees(spans)
@@ -198,9 +238,17 @@ class TestTracedQuery:
         names = tree_names(spans)
         assert names["serve.request"] == 1
         assert names["cluster.query"] == 1          # executor scatter
-        assert names["replica.coverage"] == 2       # majority quorum of 3
-        assert names["storage.scan"] == 2           # one per covered replica
-        assert names["kernel.dot_seen"] == 1        # per-query summary
+        assert names["query.stream_open"] == 2      # majority quorum of 3
+        # each stream's tombstone, then the two replicas' set clocks
+        assert names["storage.read_clock"] == 3
+        assert names["query.visibility_build"] == 2
+        assert names["kernel.h2d"] == 2
+        assert names["storage.seek"] == 2
+        # each stream pulls its chunk, then finds the range's end
+        assert names["storage.scan"] == 4
+        # 7 or 4 dots a chunk, under MIN_BATCH: tested on the host
+        assert names["query.host_seen"] == 2
+        assert "kernel.dot_seen" not in names
         assert names["query.read_repair"] == 3      # one per replayed element
         assert names["net.deliver"] == 3            # each replay delivered
 
@@ -227,7 +275,60 @@ class TestTracedQuery:
         names = tree_names(tr.spans)
         assert names["serve.request"] == 1
         assert names["cluster.query"] == 1
-        assert names["replica.coverage"] == 2
+        assert names["query.stream_open"] == 2      # one probe per replica
+        # each replica's set clock and tombstone
+        assert names["storage.read_clock"] == 4
+        assert names["storage.seek"] == 2
+        # nothing removed: no tombstone to rebuild, nothing to launch
+        assert "query.visibility_build" not in names
+        assert "kernel.dot_seen" not in names
+
+    def test_traced_range_over_a_ring_nests_per_stream_and_launch(
+            self, monkeypatch):
+        """A Range over a 2-partition ring: every child lies inside its
+        parent; one ``query.stream_open`` per replica stream; one
+        ``kernel.dot_seen`` and one ``kernel.mask_sync`` per launch; and
+        ``tombstone_runs`` sums the runs of every tombstone rebuilt."""
+        rebuilt = []
+
+        class Recording(executor.BatchVisibility):
+            def __init__(self, tombstone, **kw):
+                rebuilt.append(tombstone.n_runs())
+                super().__init__(tombstone, **kw)
+
+        monkeypatch.setattr(executor, "BatchVisibility", Recording)
+        tr = Tracer(clock=ticking_clock())
+        ring = Ring.build(["vnode0", "vnode1", "vnode2"], factor=3,
+                          n_partitions=2)
+        cluster = BigsetCluster(ring=ring, sync=True, tracer=tr)
+        elements = [b"e%03d" % i for i in range(120)]
+        for el in elements:
+            cluster.add(SET, el)
+        for el in elements[::3]:
+            cluster.remove(SET, el)
+        tr.clear()
+
+        res = cluster.query(Range(SET, limit=60), r=2)
+        assert len(res.entries) == 60
+        spans = tr.drain()
+        by_id = {s.span_id: s for s in spans}
+        for s in spans:
+            if s.parent_id is not None:
+                parent = by_id[s.parent_id]
+                assert parent.start < s.start and s.end < parent.end, s
+        count = lambda name: sum(s.name == name for s in spans)
+        assert count("query.stream_open") == 2 * 2   # partitions x r
+        assert len(rebuilt) == 4
+        launches = res.stats.kernel_launches
+        assert launches >= 2                         # a chunk per partition
+        assert count("kernel.dot_seen") == launches
+        assert count("kernel.mask_sync") == launches
+        assert count("query.pack") == launches
+        for s in spans:
+            if s.name == "kernel.dot_seen":
+                assert s.attrs["N"] % 512 == 0 and s.attrs["A"] == 8
+                assert s.attrs["R"] == 512
+        assert res.stats.tombstone_runs == sum(rebuilt) > 0
 
     @given(st.sampled_from([0.0, 0.15, 0.3]), st.sampled_from([0.0, 0.2]),
            st.booleans(), st.integers(0, 2 ** 16))
@@ -252,9 +353,9 @@ class TestTracedQuery:
         names = tree_names(tr.spans)
         # the synchronous skeleton is delivery-independent ...
         assert names["cluster.query"] == 1
-        assert names["replica.coverage"] == 2
-        assert names["storage.scan"] == 2
-        assert names["kernel.dot_seen"] == 1
+        assert names["query.stream_open"] == 2
+        assert names["storage.seek"] == 2
+        assert names["storage.scan"] >= 2
         # ... and whatever repair traffic was delivered landed in-tree
         assert names.get("net.deliver", 0) + len(tree["orphans"]) == \
             sum(1 for s in tr.spans if s.name == "net.deliver")
@@ -306,6 +407,23 @@ class TestDisabledNoop:
         assert net_off.bytes_sent == net_on.bytes_sent
         assert net_off.msgs_sent == net_on.msgs_sent
 
+    def test_null_tracer_builds_no_profiler_annotation(self, monkeypatch):
+        """Tracing off, a read with kernel launches never touches the
+        profiler; the same read in profiler mode does (the control)."""
+        def refuse(*a, **kw):
+            raise AssertionError("TraceAnnotation built")
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+        cluster = BigsetCluster(3, sync=True)
+        for i in range(80):
+            cluster.add(SET, b"n%03d" % i)
+        cluster.remove(SET, b"n000")
+        res = cluster.query(Scan(SET, page_size=100))
+        assert len(res.entries) == 79 and res.stats.kernel_launches > 0
+        cluster.tracer = Tracer(profiler=True)
+        with pytest.raises(AssertionError):
+            cluster.query(Scan(SET, page_size=100))
+
     def test_disabled_cluster_never_wraps_payloads(self):
         captured = []
         net = Network()
@@ -333,16 +451,6 @@ class TestExporters:
         client.query(Scan(SET, page_size=100))
         return tr.drain()
 
-    def test_jsonl_round_trip(self):
-        spans = self.make_spans()
-        lines = spans_to_jsonl(spans).splitlines()
-        assert len(lines) == len(spans)
-        parsed = [json.loads(ln) for ln in lines]
-        ids = {p["span_id"] for p in parsed}
-        for p in parsed:
-            assert p["parent_id"] is None or p["parent_id"] in ids
-        assert any(p["name"] == "serve.request" for p in parsed)
-
     def test_chrome_trace_round_trip(self):
         """The CI smoke check in library form: a Chrome trace-event export
         re-parses into >= 1 complete span tree."""
@@ -363,9 +471,10 @@ class TestExporters:
     def test_bytes_attrs_are_json_safe(self):
         tr = Tracer(clock=ticking_clock())
         tr.finish(tr.start("s", set_name=b"\xff\xfe", pair=[b"a", b"b"]))
-        doc = json.loads(spans_to_jsonl(tr.spans))
-        assert doc["attrs"]["pair"] == ["a", "b"]
-        assert isinstance(doc["attrs"]["set_name"], str)
+        (event,) = json.loads(json.dumps(spans_to_chrome(tr.spans)))[
+            "traceEvents"]
+        assert event["args"]["pair"] == ["a", "b"]
+        assert isinstance(event["args"]["set_name"], str)
 
 
 # ================================================================== stats op

@@ -840,7 +840,9 @@ class BigsetCluster(_ClusterBase):
             keys_scanned=sum(p.stats.keys_scanned for p in probes),
             batches=sum(p.stats.batches for p in probes),
             keys_probed=sum(p.stats.keys_probed for p in probes),
-            tombstone_runs=sum(p.stats.tombstone_runs for p in probes))
+            tombstone_runs=sum(p.stats.tombstone_runs for p in probes),
+            tombstone_builds=sum(p.stats.tombstone_builds for p in probes),
+            tombstone_reuses=sum(p.stats.tombstone_reuses for p in probes))
         per_stream = [
             frozenset(p.entries[0][1]) if p.present else None for p in probes
         ]

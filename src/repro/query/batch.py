@@ -7,9 +7,11 @@ counters)`` ``int32`` arrays and dispatches the ``kernels/dot_seen`` kernel
 (Pallas on TPU, pure-jnp reference elsewhere) so visibility for thousands of
 keys resolves in one device call.
 
-The tombstone is converted once per query into the dense
+The tombstone is converted into the dense
 :class:`~repro.core.vclock.DenseClock` *interval* form (per-actor
-``(lo, hi)`` run arrays); every chunk then reuses it.  The build is
+``(lo, hi)`` run arrays), held on the device by a :class:`DenseTombstone`;
+every chunk of every stream that reads the same tombstone ``Clock``
+object reuses it (the executor keeps one per vnode and set).  The build is
 O(interval runs) — causal metadata — with **no window cap**: the old
 bitmap form had to fall back to scalar probes beyond a fixed per-actor
 spread, but a run covers any span at constant cost.  Dots by actors the
@@ -21,8 +23,9 @@ actors to a multiple of :data:`ACTOR_BUCKET`, and runs to a power of two
 no narrower than the kernel's run tile.
 
 Spans (when the query's tracer is on): ``query.visibility_build`` around
-the rebuild, with ``kernel.h2d`` around the tombstone's copy to the
-device; per chunk ``query.pack`` (dots to arrays) or ``query.host_seen``
+a stream's visibility set-up (the executor's memo lookup, and any build),
+with ``kernel.h2d`` around a build's copy of the tombstone to the device;
+per chunk ``query.pack`` (dots to arrays) or ``query.host_seen``
 (a chunk under :data:`MIN_BATCH`), ``kernel.dot_seen`` around the launch
 and ``kernel.mask_sync`` while the host waits for the mask.
 """
@@ -68,13 +71,45 @@ def bucket_shape(n_actors: int, n_runs: int) -> Tuple[int, int]:
     return a, r
 
 
+class DenseTombstone:
+    """A tombstone as the kernel sees it: its actors' row index and its
+    runs as a bucketed :class:`~repro.core.vclock.DenseClock` whose arrays
+    are already on the device.
+
+    Immutable, and a pure function of the value of ``tombstone`` (actors
+    sorted by ``repr``, :func:`bucket_shape`, ``from_clock``).  Clocks are
+    immutable too, so an entry serves every read that returns the very
+    ``Clock`` object it was built from, and no other.
+    """
+
+    __slots__ = ("tombstone", "actor_index", "dense")
+
+    def __init__(self, tombstone: Clock, tracer: Tracer = NULL_TRACER):
+        actors = sorted(tombstone.actors(), key=repr)
+        self.actor_index: Dict[object, int] = {
+            a: i for i, a in enumerate(actors)}
+        n_actors, n_runs = bucket_shape(*dense_shape(tombstone))
+        host = from_clock(
+            tombstone, self.actor_index, n_actors, n_runs=n_runs)
+        with tracer.span("kernel.h2d"):
+            self.dense = DenseClock(
+                jnp.asarray(host.starts), jnp.asarray(host.ends))
+        self.tombstone = tombstone
+
+
 class BatchVisibility:
-    """Batched ``tombstone.seen(dot)`` over chunks of a scan stream."""
+    """Batched ``tombstone.seen(dot)`` over chunks of a scan stream.
+
+    ``dense`` is the tombstone's :class:`DenseTombstone` where the caller
+    keeps one (it must be built from this very ``tombstone``); without it
+    a non-empty tombstone is densified here.
+    """
 
     def __init__(
         self,
         tombstone: Clock,
         *,
+        dense: Optional[DenseTombstone] = None,
         use_pallas: Optional[bool] = None,
         interpret: Optional[bool] = None,
         min_batch: int = MIN_BATCH,
@@ -99,15 +134,14 @@ class BatchVisibility:
             self._mode = "empty"
             return
         self._mode = "dense"
-        with tracer.span("query.visibility_build"):
-            actors = sorted(tombstone.actors(), key=repr)
-            self._actor_index = {a: i for i, a in enumerate(actors)}
-            n_actors, n_runs = bucket_shape(*dense_shape(tombstone))
-            host = from_clock(
-                tombstone, self._actor_index, n_actors, n_runs=n_runs)
-            with tracer.span("kernel.h2d"):
-                self.dense = DenseClock(
-                    jnp.asarray(host.starts), jnp.asarray(host.ends))
+        if dense is None:
+            with tracer.span("query.visibility_build"):
+                dense = DenseTombstone(tombstone, tracer)
+        elif dense.tombstone is not tombstone:
+            raise ValueError(
+                "a DenseTombstone serves only the Clock it was built from")
+        self.dense = dense.dense
+        self._actor_index = dense.actor_index
         if stats is not None:
             stats.tombstone_runs += tombstone.n_runs()
 

@@ -30,14 +30,27 @@ Both emit byte-identical entries; the chosen strategy is reported in
 :attr:`QueryStats.strategy` and flows through the serve layer's per-page
 stats.
 
-With a tracer on, each replica stream is a ``query.stream_open`` span
-(its tombstone read, visibility build, cursor seek and first chunk);
-the executor's calls into the vnode and the LSM are ``storage.read_clock``,
-``storage.seek`` and, per chunk pulled from the raw cursor,
-``storage.scan``.  Chunk spans close before the stream yields.
+Each stream reads its replica's tombstone, and densifies it only when it
+is not the very ``Clock`` object the last stream over that vnode and set
+densified: one :class:`~repro.query.batch.DenseTombstone` per (vnode, set)
+lives on in a memo here, since executors are built per query.  A vnode
+returns the same object while the stored tombstone is unchanged, so a
+read-mostly set pays the build and the copy to the device once per
+remove or compaction, not once per stream.  ``QueryStats.tombstone_builds``
+and ``tombstone_reuses`` count the two outcomes.
+
+With a tracer on, each replica stream is a ``query.stream_open`` span:
+its tombstone read (``storage.read_clock``); where the tombstone is not
+empty, ``query.visibility_build`` around the memo lookup and any build
+(whose copy to the device is ``kernel.h2d``), and the stream span's
+attribute ``dense``, ``"build"`` or ``"reuse"`` (in the in-memory tracer;
+the profiler keeps a span's attributes as it opened); its cursor seek
+(``storage.seek``) and first chunk.  Per chunk pulled from the raw cursor
+there is a ``storage.scan``.  Chunk spans close before the stream yields.
 """
 from __future__ import annotations
 
+import weakref
 from dataclasses import dataclass, field
 from typing import Callable, Iterator, List, Optional, Tuple
 
@@ -46,9 +59,9 @@ import msgpack
 from ..core.bigset import BigsetVnode
 from ..core.clock import Clock
 from ..core.dots import Dot, DotList
-from ..obs.trace import NO_SPAN, NULL_TRACER, Tracer
+from ..obs.trace import NO_SPAN, NULL_TRACER, Span, Tracer
 from ..storage.keycodec import successor_bytes
-from .batch import BatchVisibility
+from .batch import BatchVisibility, DenseTombstone
 from .cursor import decode_cursor, encode_cursor, resume_point
 from .plan import (Count, IndexLookup, IndexRange, Join, Membership, Plan,
                    PlanError, Range, Scan)
@@ -59,6 +72,11 @@ DEFAULT_BATCH_SIZE = 1024
 # chunk size right after a positional seek: the next read should pay for a
 # probe-sized bite, not a full prefetch the gallop may immediately skip
 SEEK_CHUNK = 8
+
+# vnode -> {set name: the DenseTombstone of its current tombstone}.  Weak
+# keys: a vnode's entries, and their device buffers, go with the vnode; a
+# new tombstone replaces its set's entry.
+_DENSE = weakref.WeakKeyDictionary()
 
 
 @dataclass
@@ -74,7 +92,9 @@ class QueryStats:
                            # gallop probes), counted on hits AND misses
     kernel_launches: int = 0  # batched dot_seen dispatches this query paid
     kernel_rows: int = 0      # dots those dispatches covered (pre-padding)
-    tombstone_runs: int = 0   # real tombstone runs densified for the query
+    tombstone_runs: int = 0   # real tombstone runs its streams test against
+    tombstone_builds: int = 0  # streams that densified their tombstone
+    tombstone_reuses: int = 0  # streams that reused a dense one (memo hit)
     strategy: str = ""     # join strategy the planner executed ("" otherwise)
     coverage: str = ""     # ring coverage the cluster planned for this query
                            # ("epoch=E;partitions=P;vnodes=V;r=R")
@@ -353,9 +373,9 @@ class QueryExecutor:
     ) -> _EntryStream:
         """Visible entry stream hook (also driven by the cluster layer)."""
         stats = stats if stats is not None else QueryStats()
-        with self.tracer.span("query.stream_open"):
+        with self.tracer.span("query.stream_open") as sp:
             return _EntryStream(
-                self.vnode, set_name, self._visibility(set_name, stats),
+                self.vnode, set_name, self._visibility(set_name, stats, sp),
                 stats, start=start, end=end, after=after,
                 batch_size=self.batch_size, tracer=self.tracer)
 
@@ -371,21 +391,37 @@ class QueryExecutor:
     ) -> _IndexStream:
         """Visible posting-group stream (also driven by the cluster layer)."""
         stats = stats if stats is not None else QueryStats()
-        with self.tracer.span("query.stream_open"):
+        with self.tracer.span("query.stream_open") as sp:
             return _IndexStream(
                 self.vnode, set_name, index_name,
-                self._visibility(set_name, stats), stats,
+                self._visibility(set_name, stats, sp), stats,
                 start=start, end=end, at=at, after=after,
                 batch_size=self.batch_size, tracer=self.tracer)
 
-    def _visibility(self, set_name: bytes,
-                    stats: QueryStats) -> BatchVisibility:
-        """The set's tombstone, read and densified for batched tests."""
+    def _visibility(self, set_name: bytes, stats: QueryStats,
+                    stream: Span) -> BatchVisibility:
+        """The set's tombstone, read and densified for batched tests: the
+        memo's entry where it was built from this very Clock object."""
         with self.tracer.span("storage.read_clock"):
             tombstone = self.vnode.read_tombstone(set_name)
+        memo = _DENSE.setdefault(self.vnode, {})
+        if tombstone.is_zero():
+            memo.pop(set_name, None)
+            dense = None
+        else:
+            with self.tracer.span("query.visibility_build"):
+                dense = memo.get(set_name)
+                if dense is not None and dense.tombstone is tombstone:
+                    stats.tombstone_reuses += 1
+                    stream.set(dense="reuse")
+                else:
+                    dense = memo[set_name] = DenseTombstone(
+                        tombstone, self.tracer)
+                    stats.tombstone_builds += 1
+                    stream.set(dense="build")
         return BatchVisibility(
-            tombstone, use_pallas=self.use_pallas, interpret=self.interpret,
-            stats=stats, tracer=self.tracer)
+            tombstone, dense=dense, use_pallas=self.use_pallas,
+            interpret=self.interpret, stats=stats, tracer=self.tracer)
 
     def _read_clock(self, set_name: bytes) -> Clock:
         with self.tracer.span("storage.read_clock"):
@@ -488,8 +524,8 @@ class QueryExecutor:
         ``keys_probed`` on hits AND misses; only the element's own keys
         land in ``keys_scanned``, never the gap galloped over.
         """
-        with self.tracer.span("query.stream_open"):
-            vis = self._visibility(set_name, stats)
+        with self.tracer.span("query.stream_open") as sp:
+            vis = self._visibility(set_name, stats, sp)
         vnode = self.vnode
         tr = self.tracer
 

@@ -12,7 +12,7 @@ from __future__ import annotations
 import importlib.util
 import os
 from dataclasses import dataclass
-from typing import List
+from typing import Sequence
 
 
 @dataclass
@@ -20,7 +20,6 @@ class Deployment:
     cluster: object
     service: object
     client: object
-    set_name: bytes
     r: int
 
 
@@ -58,19 +57,22 @@ def deploy(cfg: dict, config_dir: str, name: str) -> Deployment:
         cluster = build_cluster(cfg)
     service = BigsetService(cluster)
     return Deployment(cluster, service, BigsetClient(service),
-                      cfg["set_name"].encode(), int(cfg["r"]))
+                      int(cfg["r"]))
 
 
-def populate(dep: Deployment, elements: List[bytes], removed: List[bytes],
-             batch: int) -> None:
-    """Insert ``elements`` and then remove ``removed`` through
-    ``client.batch``, ``batch`` operations to a request."""
-    client, s = dep.client, dep.set_name
-    for base in range(0, len(elements), batch):
-        client.batch(s, [["add", el] for el in elements[base:base + batch]])
-    for base in range(0, len(removed), batch):
-        chunk = removed[base:base + batch]
-        results = client.batch(s, [["remove", el] for el in chunk])
-        if not all(r.get("removed") for r in results):
-            raise RuntimeError(
-                f"set-up: a remove in batch {base // batch} removed nothing")
+def populate(dep: Deployment, sets: Sequence, batch: int) -> None:
+    """For each set (a :class:`bench.traffic.SetData`), insert its elements
+    and then remove its removed ones through ``client.batch``, ``batch``
+    operations to a request."""
+    client = dep.client
+    for s in sets:
+        for base in range(0, len(s.elements), batch):
+            client.batch(s.name, [["add", el]
+                                  for el in s.elements[base:base + batch]])
+        for base in range(0, len(s.removed), batch):
+            chunk = s.removed[base:base + batch]
+            results = client.batch(s.name, [["remove", el] for el in chunk])
+            if not all(r.get("removed") for r in results):
+                raise RuntimeError(
+                    f"set-up: a remove in batch {base // batch} of set "
+                    f"{s.name!r} removed nothing")

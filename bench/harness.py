@@ -13,6 +13,20 @@ per-layer metric is a file the harness finds by the name in
   the per-layer metric from a :class:`RunData`, or None where the run has
   nothing to read.
 
+A configuration holds one set (``"set_name"``, ``"elements"``) or many:
+``"sets": {"name_format": "links.%d", "universe": 100000, "groups":
+[{"count": 2, "elements": 20000}, {"count": 500, "elements": 40}]}``
+builds 502 sets, each of members drawn from the seed among the first
+``universe`` YCSB keys; ``remove_share`` and ``write_batch`` apply to each
+set.  A mix's ``ops`` may share among ``range``, ``probe``, ``count``
+(a whole-set ``Count``), ``join`` (the first page of a ``Join``, its limit
+drawn from ``range_length`` like a range's), ``insert`` and ``remove``;
+with many sets it adds ``"set_theta"`` (the scrambled Zipfian by which a
+request picks its set) and, with joins, ``"join": {"kind": "intersect",
+"left": [0], "right": [0, 1]}`` (the groups each side is picked from).
+A join page is a page: it counts in the ``page`` tail and carries the
+``req.page`` label; a count has the ``count`` tail and ``req.count``.
+
 The loop is open: requests are due at Poisson times and each is timed
 from when it was due to its reply, through ``BigsetClient`` and
 ``BigsetService.handle``.  One process, one thread: a request that is due
@@ -27,6 +41,7 @@ times that moved with the seed.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import gc
 import importlib.util
@@ -44,11 +59,13 @@ import numpy as np
 from . import deploy, reference, roofline, tracing, traffic
 
 CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-LABELS = {"range": "req.page", "probe": "req.probe",
-          "insert": "req.write", "remove": "req.write"}
+LABELS = {"range": "req.page", "join": "req.page", "probe": "req.probe",
+          "count": "req.count", "insert": "req.write", "remove": "req.write"}
 # the request kinds an end-to-end tail ``<group>_p<q>_ms`` is taken over
-TAILS = {"page": ("range",), "probe": ("probe",),
-         "write": ("insert", "remove")}
+TAILS = {"page": ("range", "join"), "probe": ("probe",),
+         "count": ("count",), "write": ("insert", "remove")}
+# the read kinds: each request's QueryStats is kept, and warm-up sends some
+READS = ("range", "probe", "count", "join")
 # requests due in the window but not started this long after it closed
 # are counted as failed, so an overloaded run still ends in time
 DRAIN_S = 30.0
@@ -208,7 +225,7 @@ class RunData:
     answers: List[reference.Answer]
     latency: Dict[str, List[float]]    # seconds from due to reply, by kind
     service: Dict[str, List[float]]    # seconds from call to reply, by kind
-    stats: Dict[str, List[dict]]       # per-request QueryStats, reads only
+    stats: Dict[str, List[dict]]       # per-request QueryStats, by read kind
     completed_in_window: int
     dispatch: object                   # DispatchStats delta over the window
     io: object                         # IoStats delta over the window
@@ -252,17 +269,25 @@ def _annotate(on: bool):
     return lambda _name: contextlib.nullcontext()
 
 
+def _answer(op: traffic.Op) -> reference.Answer:
+    return reference.Answer(op.kind, op.key, op.limit, op.set_name,
+                            op.right, op.join_kind)
+
+
 def execute(dep: deploy.Deployment, op: traffic.Op):
     """Send one request; return its answer, its QueryStats (reads), and
     whether a cursor lease is left open."""
-    from repro.query import Membership, Range
+    from repro.query import Count, Join, Membership, Range
     from repro.serve.bigset_service import Backpressure
 
-    c, s = dep.client, dep.set_name
-    a = reference.Answer(op.kind, op.key, op.limit)
+    c, s = dep.client, op.set_name
+    a = _answer(op)
     try:
-        if op.kind == "range":
-            page = c.query(Range(s, start=op.key, limit=op.limit), r=dep.r)
+        if op.kind in ("range", "join"):
+            plan = (Range(s, start=op.key, limit=op.limit)
+                    if op.kind == "range" else
+                    Join(op.join_kind, s, op.right, limit=op.limit))
+            page = c.query(plan, r=dep.r)
             a.members = page.members
             a.ok = True
             return a, page.stats, page.cursor is not None
@@ -271,38 +296,69 @@ def execute(dep: deploy.Deployment, op: traffic.Op):
             a.present = bool(page.present)
             a.ok = True
             return a, page.stats, False
+        if op.kind == "count":
+            page = c.query(Count(s), r=dep.r)
+            a.count = page.count
+            a.ok = True
+            return a, page.stats, False
         if op.kind == "insert":
             c.insert(s, op.key)
-        else:
+        elif op.kind == "remove":
             a.removed = bool(c.remove(s, op.key))
+        else:
+            raise ValueError(f"unknown op kind {op.kind!r}")
     except Backpressure:
         return a, None, False
     a.ok = True
     return a, None, False
 
 
-def warm(dep: deploy.Deployment, ops: List[traffic.Op]) -> int:
+def warm(dep: deploy.Deployment, sets: List[traffic.SetData],
+         ops: List[traffic.Op]) -> int:
     """Compile every kernel shape the window's reads reach, then send a few
-    read-only requests of each read kind.  Returns the shapes warmed."""
+    read-only requests of each read kind.  Returns the shapes warmed.
+
+    A tombstone's shape is that of each set's storage sets on every vnode,
+    and each it can take as it gains at most one run for each remove the
+    window sends to the set.  A batch
+    is a chunk of a stream padded to :data:`PAD_BUCKET` dots.  Chunks grow
+    32, 128, 512 dots, then the executor's batch size: counts, joins and
+    range pages of more than 32 + 128 members may read that far (a page,
+    past dead elements), so their batches are warmed at every width.
+    """
     import jax.numpy as jnp
 
     from repro.core.vclock import DenseClock
     from repro.kernels.dot_seen import dot_seen
     from repro.query.batch import PAD_BUCKET, bucket_shape, dense_shape
+    from repro.query.executor import DEFAULT_BATCH_SIZE
 
     cluster = dep.cluster
+    removes = collections.Counter(o.set_name for o in ops
+                                  if o.kind == "remove")
     shapes = set()
-    for pset in cluster.ring.storage_sets(dep.set_name):
-        for vn in cluster.vnodes.values():
-            ts = vn.read_tombstone(pset)
-            if not ts.is_zero():
-                shapes.add(bucket_shape(*dense_shape(ts)))
-    zeros = np.zeros((PAD_BUCKET,), np.int32)
-    for a, r in sorted(shapes):
-        clock = DenseClock(jnp.ones((a, r), jnp.int32),
-                           jnp.zeros((a, r), jnp.int32))
-        np.asarray(dot_seen(clock, zeros, zeros))
-    for kind in ("range", "probe"):
+    for s in sets:
+        grow = removes[s.name]
+        for pset in cluster.ring.storage_sets(s.name):
+            for vn in cluster.vnodes.values():
+                ts = vn.read_tombstone(pset)
+                a, r = dense_shape(ts)
+                if not ts.is_zero():
+                    shapes.add(bucket_shape(a, r))
+                # every bucket the tombstone can pass through on the way
+                shapes.update(bucket_shape(max(a, 1), r + k)
+                              for k in range(1, grow + 1))
+    deep = any(o.kind in ("count", "join") or (o.limit or 0) > 32 + 128
+               for o in ops)
+    widest = (-(-DEFAULT_BATCH_SIZE // PAD_BUCKET) * PAD_BUCKET if deep
+              else PAD_BUCKET)
+    for n in range(PAD_BUCKET, widest + 1, PAD_BUCKET):
+        zeros = np.zeros((n,), np.int32)
+        for a, r in sorted(shapes):
+            clock = DenseClock(jnp.ones((a, r), jnp.int32),
+                               jnp.zeros((a, r), jnp.int32))
+            np.asarray(dot_seen(clock, zeros, zeros))
+    for kind in READS:
         for op in [o for o in ops if o.kind == kind][:WARM_REQUESTS]:
             _a, _stats, leased = execute(dep, op)
             if leased:
@@ -321,7 +377,7 @@ def run_window(dep: deploy.Deployment, ops: List[traffic.Op],
     kinds = sorted({o.kind for o in ops})
     latency = {k: [] for k in kinds}
     service = {k: [] for k in kinds}
-    stats: Dict[str, List[dict]] = {"range": [], "probe": []}
+    stats: Dict[str, List[dict]] = {k: [] for k in READS}
     answers: List[reference.Answer] = []
     d0 = DISPATCHES.snapshot()
     io0 = dep.cluster.io_stats()
@@ -342,7 +398,7 @@ def run_window(dep: deploy.Deployment, ops: List[traffic.Op],
                         pass
             start = time.perf_counter()
             if start > give_up:
-                answers.append(reference.Answer(op.kind, op.key, op.limit))
+                answers.append(_answer(op))
                 continue
             with ann(LABELS[op.kind]):
                 a, st, leased = execute(dep, op)
@@ -365,36 +421,40 @@ def run_window(dep: deploy.Deployment, ops: List[traffic.Op],
         net_bytes=dep.cluster.net.bytes_sent - net0)
 
 
-def read_back(dep: deploy.Deployment) -> List[bytes]:
-    """The whole set, paginated at the configuration's quorum."""
+def read_back(dep: deploy.Deployment,
+              sets: List[traffic.SetData]) -> Dict[bytes, List[bytes]]:
+    """Each set whole, paginated at the configuration's quorum."""
     from repro.query import Scan
 
-    out: List[bytes] = []
+    out: Dict[bytes, List[bytes]] = {}
     page_size = dep.service.config.max_page_size
-    for page in dep.client.pages(Scan(dep.set_name, page_size=page_size),
-                                 r=dep.r):
-        out.extend(page.members)
+    for s in sets:
+        out[s.name] = [el for page in dep.client.pages(
+            Scan(s.name, page_size=page_size), r=dep.r)
+            for el in page.members]
     return out
 
 
 def on_replicas(dep: deploy.Deployment,
-                keys: List[bytes]) -> Dict[bytes, List[bool]]:
-    """Each key's presence on every replica its partition is written to,
-    read from that replica alone (no quorum merge, no read repair)."""
+                keys: List[reference.Key]) -> Dict[reference.Key, List[bool]]:
+    """Each (set, element)'s presence on every replica its partition is
+    written to, read from that replica alone (no quorum merge, no read
+    repair)."""
     ring = dep.cluster.ring
-    out: Dict[bytes, List[bool]] = {}
-    for key in keys:
-        pid = ring.partition(dep.set_name, key)
-        pset = ring.storage_set(dep.set_name, pid)
-        out[key] = [dep.cluster.vnodes[a].is_member(pset, key)[0]
-                    for a in ring.owners(pid)]
+    out: Dict[reference.Key, List[bool]] = {}
+    for s, key in keys:
+        pid = ring.partition(s, key)
+        pset = ring.storage_set(s, pid)
+        out[s, key] = [dep.cluster.vnodes[a].is_member(pset, key)[0]
+                       for a in ring.owners(pid)]
     return out
 
 
-def after_power_loss(dep: deploy.Deployment,
-                     keys: List[bytes]) -> Dict[bytes, List[bool]]:
-    """Each key's presence on every replica its partition is written to,
-    after every vnode lost power at once and came back from its own media.
+def after_power_loss(dep: deploy.Deployment, keys: List[reference.Key]
+                     ) -> Dict[reference.Key, List[bool]]:
+    """Each (set, element)'s presence on every replica its partition is
+    written to, after every vnode lost power at once and came back from
+    its own media.
 
     First ``sync_all``, the program's acknowledgement barrier: it fsyncs
     what each store's group commit still holds.  Then every vnode crashes
@@ -409,16 +469,16 @@ def after_power_loss(dep: deploy.Deployment,
     for a in actors:
         cluster.restart(a)
     held: Dict[tuple, frozenset] = {}
-    out: Dict[bytes, List[bool]] = {}
-    for key in keys:
-        pid = ring.partition(dep.set_name, key)
-        pset = ring.storage_set(dep.set_name, pid)
+    out: Dict[reference.Key, List[bool]] = {}
+    for s, key in keys:
+        pid = ring.partition(s, key)
+        pset = ring.storage_set(s, pid)
         row = []
         for a in ring.owners(pid):
             if (a, pset) not in held:
                 held[a, pset] = cluster.vnodes[a].value(pset)
             row.append(key in held[a, pset])
-        out[key] = row
+        out[s, key] = row
     return out
 
 
@@ -475,25 +535,25 @@ def _run(cell, seed, seconds, trace, t_start, root, require_chip, device,
 
     dep = deploy.deploy(cfg, os.path.join(root, "bench", "configs"),
                         cell.config)
-    n = int(cfg["elements"])
-    elements = traffic.ycsb_keys(n)
-    rng = traffic.rng_for(seed, 0)
-    gone = rng.permutation(n)[: int(round(n * cfg["remove_share"]))]
-    removed = [elements[i] for i in sorted(gone.tolist())]
-    removed_set = set(removed)
+    sets, first_new_keynum = traffic.data(cfg, seed)
     t0 = time.perf_counter()
-    deploy.populate(dep, elements, removed, int(cfg["write_batch"]))
-    log(f"populate: {n} inserted, {len(removed)} removed in "
+    deploy.populate(dep, sets, int(cfg["write_batch"]))
+    log(f"populate: {len(sets)} sets, "
+        f"{sum(len(s.elements) for s in sets)} inserted, "
+        f"{sum(len(s.removed) for s in sets)} removed in "
         f"{time.perf_counter() - t0:.3f}s")
-    initial = [el for el in elements if el not in removed_set]
+    initial = {}
+    for s in sets:
+        gone = set(s.removed)
+        initial[s.name] = [el for el in s.elements if el not in gone]
     gc.collect()
     gc.freeze()
     log(f"heap frozen: {gc.get_freeze_count()} objects")
-    ops = traffic.schedule(cell.mix, rate, seconds, seed, elements,
-                           set(initial), first_new_keynum=n)
+    ops = traffic.schedule(cell.mix, rate, seconds, seed, sets,
+                           first_new_keynum)
     fault_cm = fault() if fault is not None else contextlib.nullcontext()
     with fault_cm:
-        shapes = warm(dep, ops)
+        shapes = warm(dep, sets, ops)
         log(f"warm: {shapes} kernel shapes, {compiles.count} compiles "
             f"({compiles.cache_hits} persistent-cache hits)")
         launches: List[tuple] = []
@@ -516,13 +576,14 @@ def _run(cell, seed, seconds, trace, t_start, root, require_chip, device,
         run.launches = launches
         memory = _memory_peak()
         t0 = time.perf_counter()
-        scan = read_back(dep)
-        written = [a.key for a in run.answers if a.ok and
+        scan = read_back(dep, sets)
+        written = [(a.set_name, a.key) for a in run.answers if a.ok and
                    a.kind in ("insert", "remove")]
         replicas = on_replicas(dep, written)
         t1 = time.perf_counter()
         restarted = (after_power_loss(dep, list(dict.fromkeys(
-            elements + written))) if cfg.get("durable") else None)
+            [(s.name, el) for s in sets for el in s.elements] + written)))
+            if cfg.get("durable") else None)
         log(f"read-back {t1 - t0:.3f}s, power loss and restart "
             f"{time.perf_counter() - t1:.3f}s")
     served = sum(1 for a in run.answers if a.ok)

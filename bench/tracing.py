@@ -3,9 +3,10 @@
 The reduction works on plain interval lists, so a test can feed it a
 small recorded trace: device operations ``(name, start_ns, duration_ns)``
 per chip, and the benchmark's own host annotations (``bench.window``
-around the window, ``req.page`` / ``req.probe`` / ``req.write`` around
-each request, ``gen.idle`` while the generator waits for the next due
-time).  :func:`load` pulls those lists out of an ``.xplane.pb``.
+around the window, ``req.page`` (range and join pages) / ``req.probe`` /
+``req.count`` / ``req.write`` around each request, ``gen.idle`` while the
+generator waits for the next due time).  :func:`load` pulls those lists
+out of an ``.xplane.pb``.
 """
 from __future__ import annotations
 
@@ -17,7 +18,8 @@ from dataclasses import dataclass, field
 from typing import Dict, Iterable, List, Sequence, Tuple
 
 WINDOW = "bench.window"
-HOST_LABELS = ("req.page", "req.probe", "req.write", "gen.idle")
+HOST_LABELS = ("req.page", "req.probe", "req.count", "req.write",
+               "gen.idle")
 # device-plane lines, most specific first: operations, then whole programs
 OP_LINES = ("XLA Ops",)
 MODULE_LINES = ("XLA Modules",)

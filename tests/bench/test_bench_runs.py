@@ -17,7 +17,7 @@ import pytest
 from bench import harness, run
 
 SMALL = {"elements": 1500}
-CELLS = ["full3.ycsb_e", "ring64.ycsb_e", "full3.probe_write"]
+CELLS = ["full3.ycsb_e", "ring64.ycsb_e", "full3.probe_write", "links.mix"]
 DURABLE = {"ring64.ycsb_e"}
 
 
@@ -36,6 +36,20 @@ def test_sound_run_is_correct(workload, cells_root):
     assert all(c["value"] == 0 for c in res["checks"].values())
     # only a durable configuration is read back after a power loss
     assert ("lost_on_restart" in res["checks"]) == (workload in DURABLE)
+
+
+@pytest.mark.parametrize("kind", ["union", "difference"])
+def test_every_join_kind_is_correct(kind, cells_root):
+    # the many-set cell's own mix joins by intersect
+    path = os.path.join(cells_root, "bench", "traffic", "links_mix.json")
+    with open(path) as f:
+        mix = json.load(f)
+    mix["join"]["kind"] = kind
+    with open(path, "w") as f:
+        json.dump(mix, f)
+    res = small_run("links.mix", rate=20, root=cells_root)
+    assert res["correct"], res["checks"]
+    assert res["failed"] == 0
 
 
 @pytest.mark.parametrize("trace", [False, True])
@@ -165,6 +179,50 @@ def no_replication():
                    lambda orig: lambda self, *a, **k: None)
 
 
+def join_drops_a_member():
+    """An answer altered where it is produced: every join, zipper or
+    gallop, loses its first member."""
+    from repro.cluster import clusters
+
+    def make(orig):
+        def join(*a, **k):
+            entries = iter(orig(*a, **k))
+            next(entries, None)
+            return entries
+        return join
+
+    stack = contextlib.ExitStack()
+    stack.enter_context(patched(clusters, "zipper_join", make))
+    stack.enter_context(patched(clusters, "gallop_join", make))
+    return stack
+
+
+def count_off_by_one():
+    """An answer altered where it is produced: every count one too many."""
+    from repro.cluster import clusters
+
+    def make(orig):
+        def q_count(self, *a, **k):
+            res = orig(self, *a, **k)
+            res.count += 1
+            return res
+        return q_count
+    return patched(clusters.BigsetCluster, "_q_count", make)
+
+
+def insert_into_the_wrong_set():
+    """A step applied to the wrong state: every insert lands in another
+    set of the deployment."""
+    from repro.cluster import clusters
+
+    def make(orig):
+        def add(self, set_name, element, *a, **k):
+            other = b"links.1" if set_name == b"links.0" else b"links.0"
+            return orig(self, other, element, *a, **k)
+        return add
+    return patched(clusters.BigsetCluster, "add", make)
+
+
 def fsync_does_nothing():
     """A later or rarer flush: the write-ahead log's fsync keeps nothing,
     so what the window wrote lives in memory alone."""
@@ -187,6 +245,9 @@ def fsync_does_nothing():
     ("full3.ycsb_e", no_replication, "lost_writes"),
     ("ring64.ycsb_e", no_replication, "lost_writes"),
     ("ring64.ycsb_e", fsync_does_nothing, "lost_on_restart"),
+    ("links.mix", join_drops_a_member, "wrong_pages"),
+    ("links.mix", count_off_by_one, "wrong_counts"),
+    ("links.mix", insert_into_the_wrong_set, "lost_writes"),
 ])
 def test_fault_makes_the_run_incorrect(workload, fault, number, cells_root):
     res = small_run(workload, fault=fault, rate=40, root=cells_root)
